@@ -27,6 +27,10 @@ import numpy as np
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 
+from repro.compile_cache import place_compile_cache                   # noqa: E402
+
+place_compile_cache()
+
 from repro.core.predictor import (LatencyPredictor, sample_conv_ops,   # noqa: E402
                                   sample_linear_ops, train_predictor)
 from repro.core.predictor.gbdt import GBDTParams                      # noqa: E402

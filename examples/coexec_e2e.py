@@ -7,14 +7,17 @@ Part 1: compile ResNet-18 for GPU + 3 CPU threads on the Moto 2022 model
         group and ops run unsplit; the fidelity summary still pairs
         executed wall time with the plan's predictions per op).
 Part 2: run an actual uneven channel-split matmul across two device groups
-        via shard_map (subprocess with 8 virtual devices).
+        via shard_map, in this process.  It needs at least two devices (a
+        multi-chip host, or virtual CPU devices as in the second command)
+        and says why it skips when there is one.
 
     PYTHONPATH=src python examples/coexec_e2e.py
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+        PYTHONPATH=src python examples/coexec_e2e.py
+
+Any failure exits non-zero.
 """
-import os
-import subprocess
 import sys
-import textwrap
 from pathlib import Path
 
 ROOT = Path(__file__).resolve().parents[1]
@@ -56,13 +59,21 @@ def part1():
     print(compiled.last_report.fidelity_summary())
 
 
-_PART2 = textwrap.dedent("""
-    import os
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    import jax, jax.numpy as jnp, numpy as np
-    from repro.core.coexec import (coexec_matmul, coexec_mesh, pack_weights,
-                                   throughput_split)
+def part2():
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.core.coexec import (coexec_matmul, coexec_mesh, mesh_groups,
+                                   pack_weights, throughput_split)
+    n = len(jax.devices())
+    print(f"\n== Part 2: shard_map channel-split matmul ({n} devices) ==")
     mesh = coexec_mesh()
+    if mesh_groups(mesh) < 2:
+        print("skipped: one device gives a one-group mesh, with nothing to "
+              "split against (see the module docstring for a multi-device "
+              "run)")
+        return
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.normal(size=(50, 768)), jnp.float32)
     w = jnp.asarray(rng.normal(size=(768, 3072)), jnp.float32)
@@ -73,16 +84,6 @@ _PART2 = textwrap.dedent("""
                                rtol=2e-4, atol=2e-4)
     print(f"channel split: {plan.c_fast} fast-group / {plan.c_slow} "
           f"slow-group channels (padded to {plan.c_pad}) -- results match")
-""")
-
-
-def part2():
-    print("\n== Part 2: shard_map channel-split matmul (8 virt devices) ==")
-    env = dict(os.environ)
-    env["PYTHONPATH"] = str(ROOT / "src")
-    out = subprocess.run([sys.executable, "-c", _PART2], env=env,
-                         capture_output=True, text=True, timeout=300)
-    print(out.stdout.strip() or out.stderr[-800:])
 
 
 if __name__ == "__main__":

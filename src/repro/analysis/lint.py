@@ -47,7 +47,7 @@ LINT_RULES = {
 #: core/coexec, kernels/*/ops, launch/, models/, serving is control-plane
 #: so it IS bound) are exempt by omission.
 IMPORT_LIGHT_GLOBS = (
-    "__init__.py", "__main__.py", "api.py", "cli.py",
+    "__init__.py", "__main__.py", "api.py", "cli.py", "compile_cache.py",
     "graph/*.py", "measure/*.py", "serving/*.py", "analysis/*.py",
     "core/*.py", "core/predictor/*.py", "core/simulator/*.py",
     "runtime/__init__.py", "runtime/plan.py", "runtime/cache.py",

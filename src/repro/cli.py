@@ -377,9 +377,11 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     # bench/serve forward their whole tail verbatim; dispatch before
     # argparse so leading options (`serve --arch ...`) survive (argparse
     # REMAINDER refuses option-looking tokens in first position)
+    from repro.compile_cache import place_compile_cache
     if argv[:1] == ["bench"]:
         return _cmd_bench(argv[1:])
     if argv[:1] == ["serve"]:
+        place_compile_cache()
         return _cmd_serve(argv[1:])
     ap = argparse.ArgumentParser(
         prog="python -m repro",
@@ -479,6 +481,8 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                         "repro.launch.serve")
 
     args = ap.parse_args(argv)
+    if args.cmd in ("execute", "calibrate", "tune"):
+        place_compile_cache()               # before these import jax
     try:
         if args.cmd == "plan":
             return _cmd_plan(args)

@@ -123,15 +123,6 @@ def mesh_groups(mesh: Mesh) -> int:
     return int(mesh.shape[COEXEC_AXIS])
 
 
-def _shard_map():
-    # jax.shard_map graduated from jax.experimental in newer releases;
-    # support both spellings.
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    return sm
-
-
 _PROGRAM_CACHE: dict = {}
 
 
@@ -218,13 +209,11 @@ def gather_stacked_traced(y: jax.Array, plan: SplitPlan,
     def merge(y_local: jax.Array) -> jax.Array:
         return _merge_stacked(y_local, plan)
 
-    kwargs = dict(mesh=mesh, in_specs=(_stacked_spec(y.ndim),),
-                  out_specs=P())
-    try:
-        fn = _shard_map()(merge, check_rep=False, **kwargs)
-    except TypeError:       # jax versions without the check_rep knob
-        fn = _shard_map()(merge, **kwargs)
-    return fn(y)
+    # the all-gathers leave every device the same value, but shard_map's
+    # varying-axis inference cannot see that: the replication is asserted
+    return jax.shard_map(merge, mesh=mesh,
+                         in_specs=(_stacked_spec(y.ndim),),
+                         out_specs=P(), check_vma=False)(y)
 
 
 def coexec_matmul(x: jax.Array, packed_w: jax.Array, plan: SplitPlan,
@@ -246,7 +235,7 @@ def coexec_matmul(x: jax.Array, packed_w: jax.Array, plan: SplitPlan,
             return (x_full @ w_l[0])[None]    # (1, L, c_pad)
 
         x_spec = _stacked_spec(3) if x_plan is not None else P()
-        return _shard_map()(
+        return jax.shard_map(
             local, mesh=mesh,
             in_specs=(x_spec, _stacked_spec(3)),
             out_specs=_stacked_spec(3))
@@ -286,7 +275,7 @@ def coexec_conv2d(x: jax.Array, packed_w: jax.Array, plan: SplitPlan,
             return y[None]                    # (1, B, H', W', c_pad)
 
         x_spec = _stacked_spec(5) if x_plan is not None else P()
-        return _shard_map()(
+        return jax.shard_map(
             local, mesh=mesh,
             in_specs=(x_spec, _stacked_spec(5)),
             out_specs=_stacked_spec(5))

@@ -11,8 +11,8 @@ processing all `group = n_heads // n_kv_heads` query heads of one KV head
 together as the row dimension of the MXU ops.
 
 Causality/window masking is positional: positions > pos (and, for sliding
-windows, <= pos - window) are masked.  `pos` arrives as a (1,1) scalar
-input; the window is static.
+windows, <= pos - window) are masked.  `pos` arrives as a (1,) SMEM
+scalar; the window is static.
 """
 from __future__ import annotations
 
@@ -47,7 +47,7 @@ def _decode_attn_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref,
     scores = jnp.dot(q, k.T, preferred_element_type=jnp.float32) \
         * (hd ** -0.5)                             # (g, bs)
 
-    pos = pos_ref[0, 0]
+    pos = pos_ref[0]
     k_pos = s_idx * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
     mask = k_pos <= pos
     if window > 0:
@@ -83,7 +83,9 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     s, kv, _ = k.shape
     g = h // kv
     g_pad = max(8, -(-g // 8) * 8)
-    bs = _check_tile("bs", bs, 512, s, 1, lim_align=128)
+    # bs is the second-minor dim of the (1, bs, hd) cache block: the TPU
+    # lowering needs it a multiple of 8
+    bs = _check_tile("bs", bs, 512, s, 8, lim_align=128)
 
     # (kv, g_pad, hd) query layout; (kv, S_pad, hd) cache layout
     qg = q.reshape(kv, g, hd)
@@ -98,14 +100,14 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         vt = jnp.pad(vt, ((0, 0), (0, s_pad), (0, 0)))
     sp = kt.shape[1]
     grid = (kv, sp // bs)
-    pos_arr = jnp.asarray(pos, jnp.int32).reshape(1, 1)
+    pos_arr = jnp.asarray(pos, jnp.int32).reshape(1)
 
     out = pl.pallas_call(
         functools.partial(_decode_attn_kernel, bs=bs, n_s=grid[1],
                           window=window),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1), lambda hh, ss: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, g_pad, hd), lambda hh, ss: (hh, 0, 0)),
             pl.BlockSpec((1, bs, hd), lambda hh, ss: (hh, ss, 0)),
             pl.BlockSpec((1, bs, hd), lambda hh, ss: (hh, ss, 0)),

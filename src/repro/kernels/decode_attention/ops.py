@@ -11,7 +11,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.coexec import (COEXEC_AXIS, LANE_AXIS, _merge_stacked,
-                               _shard_map, _stacked_spec,
+                               _stacked_spec,
                                cached_coexec_program, gather_stacked,
                                mesh_fingerprint, split_for_mesh)
 from repro.kernels import registry
@@ -153,10 +153,7 @@ def run_head_split(x, packed, split, mesh, op, n_fast, *, gather=True,
                       in_specs=(x_spec,
                                 P(COEXEC_AXIS, None, None, None, None)),
                       out_specs=_stacked_spec(3))
-        try:
-            return _shard_map()(local, check_rep=False, **kwargs)
-        except TypeError:       # jax versions without the check_rep knob
-            return _shard_map()(local, **kwargs)
+        return jax.shard_map(local, **kwargs)
 
     key = ("attn-head", op, n_fast, x_plan, mesh_fingerprint(mesh),
            tuple(x.shape), str(x.dtype), str(packed.dtype), tile)
@@ -245,13 +242,12 @@ def _build_kv_block_program(x_plan, mesh, op, n_fast, s_pad, g):
         return out.reshape(1, op.H * op.hd).astype(x_full.dtype)
 
     x_spec = _stacked_spec(3) if x_plan is not None else P()
-    kwargs = dict(mesh=mesh,
-                  in_specs=(x_spec, P(COEXEC_AXIS, None, None, None, None)),
-                  out_specs=P())
-    try:
-        return _shard_map()(local, check_rep=False, **kwargs)
-    except TypeError:
-        return _shard_map()(local, **kwargs)
+    # the merge reads all-gathered partials, so every device holds the same
+    # output; shard_map's varying-axis inference cannot see that
+    return jax.shard_map(
+        local, mesh=mesh,
+        in_specs=(x_spec, P(COEXEC_AXIS, None, None, None, None)),
+        out_specs=P(), check_vma=False)
 
 
 registry.register_split_lowering("attention", "kv-block",

@@ -496,10 +496,10 @@ class TileSpec:
         With ``preserve_numerics`` (the default, and the only mode the
         autotuner selects from unless explicitly told otherwise) every
         reduction-axis param is pinned to its default-resolved value, so
-        each candidate computes bit-identical fp32 results to the default
-        config — varying only how the *output* space is tiled.  With
-        ``preserve_numerics=False`` the reduction params are searched too;
-        those candidates are tolerance-exact, not bit-identical.
+        each candidate varies only how the *output* space is tiled and
+        agrees with the default config to fp32 rounding.  With
+        ``preserve_numerics=False`` the reduction params are searched too,
+        which reassociates the reduction itself.
         """
         extents = tile_extents(op)
         default = self.default_config(op)

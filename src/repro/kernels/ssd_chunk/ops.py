@@ -11,7 +11,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.coexec import (COEXEC_AXIS, LANE_AXIS, _merge_stacked,
-                               _shard_map, _stacked_spec,
+                               _stacked_spec,
                                cached_coexec_program, gather_stacked,
                                mesh_fingerprint, split_for_mesh)
 from repro.kernels import registry
@@ -200,12 +200,12 @@ def run_state_split(x, packed, split, mesh, op, n_fast, *, gather=True,
             return out[None]                     # (1, T, c_pad / lanes)
 
         x_spec = _stacked_spec(3) if x_plan is not None else P()
-        kwargs = dict(mesh=mesh, in_specs=(x_spec, P(COEXEC_AXIS, None)),
-                      out_specs=_stacked_spec(3))
-        try:
-            return _shard_map()(local, check_rep=False, **kwargs)
-        except TypeError:       # jax versions without the check_rep knob
-            return _shard_map()(local, **kwargs)
+        # check_vma=False: a chained input varies over both mesh axes while
+        # the scan's initial state varies over the group axis only, and
+        # scan needs its carry's varying axes fixed
+        return jax.shard_map(local, mesh=mesh,
+                             in_specs=(x_spec, P(COEXEC_AXIS, None)),
+                             out_specs=_stacked_spec(3), check_vma=False)
 
     key = ("ssm-state", op, n_fast, x_plan, mesh_fingerprint(mesh),
            tuple(x.shape), str(x.dtype), str(packed.dtype), tile)

@@ -23,7 +23,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.tiles import check_chunk as _check_chunk
 
 
-def _ssd_chunk_kernel(a_ref, x_ref, b_ref, c_ref, dt_ref, s0_ref,
+def _ssd_chunk_kernel(x_ref, b_ref, c_ref, dt_ref, l_ref, lrow_ref, s0_ref,
                       y_ref, sf_ref, state_ref, *, n_chunks: int, L: int):
     nc = pl.program_id(2)
 
@@ -31,14 +31,16 @@ def _ssd_chunk_kernel(a_ref, x_ref, b_ref, c_ref, dt_ref, s0_ref,
     def _init():
         state_ref[...] = s0_ref[0, 0].astype(jnp.float32)
 
-    a = a_ref[0, 0]                                   # scalar decay coeff
     x = x_ref[0, 0, 0].astype(jnp.float32)            # (L, hd)
     b = b_ref[0, 0, 0].astype(jnp.float32)            # (L, N)
     c = c_ref[0, 0, 0].astype(jnp.float32)            # (L, N)
     dt = dt_ref[0, 0, 0].astype(jnp.float32)          # (L, 1)
-
-    logd = dt * a                                     # (L, 1), <= 0
-    l = jnp.cumsum(logd, axis=0)                      # (L, 1)
+    l = l_ref[0, 0, 0]                                # (L, 1) cum. log-decay
+    l_row = lrow_ref[0, 0, 0]                         # (1, L) same, as a row
+    # l at the chunk's last step, as a masked sum (exact: one term is
+    # nonzero) since Mosaic cannot broadcast an offset (1, 1) slice
+    last = jax.lax.broadcasted_iota(jnp.int32, (L, 1), 0) == L - 1
+    l_end = jnp.sum(jnp.where(last, l, 0.0), axis=0, keepdims=True)  # (1, 1)
 
     h0 = state_ref[...]                               # (hd, N)
     # inter-chunk: y_t += exp(l_t) * C_t . h0
@@ -46,7 +48,7 @@ def _ssd_chunk_kernel(a_ref, x_ref, b_ref, c_ref, dt_ref, s0_ref,
                                    preferred_element_type=jnp.float32)
     # intra-chunk: W_{tj} = (C_t.B_j) exp(l_t - l_j), j <= t
     s_cb = jnp.dot(c, b.T, preferred_element_type=jnp.float32)  # (L, L)
-    ldiff = l - l.reshape(1, L)                       # l_t - l_j
+    ldiff = l - l_row                                 # l_t - l_j
     causal = (jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
               >= jax.lax.broadcasted_iota(jnp.int32, (L, L), 1))
     w = jnp.where(causal, jnp.exp(ldiff) * s_cb, 0.0)
@@ -55,8 +57,8 @@ def _ssd_chunk_kernel(a_ref, x_ref, b_ref, c_ref, dt_ref, s0_ref,
         w, xdt, preferred_element_type=jnp.float32)).astype(y_ref.dtype)
 
     # state update: h' = exp(l_L) h0 + sum_j exp(l_L - l_j) dt_j x_j B_j^T
-    decay_end = jnp.exp(l[L - 1] - l)                 # (L, 1)
-    state_ref[...] = jnp.exp(l[L - 1]) * h0 + jnp.dot(
+    decay_end = jnp.exp(l_end - l)                    # (L, 1)
+    state_ref[...] = jnp.exp(l_end) * h0 + jnp.dot(
         (xdt * decay_end).T, b, preferred_element_type=jnp.float32)
 
     @pl.when(nc == n_chunks - 1)
@@ -87,14 +89,19 @@ def ssd_chunk_scan(x: jax.Array, b: jax.Array, c: jax.Array,
     cc = jnp.broadcast_to(c[:, None], (bsz, h, t, n)) \
         .reshape(bsz, h, nch, L, n)
     dtc = dt.transpose(0, 2, 1).reshape(bsz, h, nch, L, 1)
-    a2 = jnp.broadcast_to(a[None, :], (bsz, h))
+    # the within-chunk cumulative log-decay l_t = sum_{j<=t} dt_j a is
+    # taken here: the TPU kernel lowering has no cumsum, and the kernel
+    # needs l both as a column and as a row
+    logd = dtc.astype(jnp.float32) * a.astype(jnp.float32)[None, :, None,
+                                                            None, None]
+    lc = jnp.cumsum(logd, axis=3)                     # (B, H, nch, L, 1)
+    lr = lc.reshape(bsz, h, nch, 1, L)
 
     grid = (bsz, h, nch)
     y, sf = pl.pallas_call(
         functools.partial(_ssd_chunk_kernel, n_chunks=nch, L=L),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1), lambda i, j, k: (i, j)),            # a
             pl.BlockSpec((1, 1, 1, L, hd),
                          lambda i, j, k: (i, j, k, 0, 0)),
             pl.BlockSpec((1, 1, 1, L, n),
@@ -102,6 +109,10 @@ def ssd_chunk_scan(x: jax.Array, b: jax.Array, c: jax.Array,
             pl.BlockSpec((1, 1, 1, L, n),
                          lambda i, j, k: (i, j, k, 0, 0)),
             pl.BlockSpec((1, 1, 1, L, 1),
+                         lambda i, j, k: (i, j, k, 0, 0)),
+            pl.BlockSpec((1, 1, 1, L, 1),
+                         lambda i, j, k: (i, j, k, 0, 0)),
+            pl.BlockSpec((1, 1, 1, 1, L),
                          lambda i, j, k: (i, j, k, 0, 0)),
             pl.BlockSpec((1, 1, hd, n), lambda i, j, k: (i, j, 0, 0)),
         ],
@@ -116,6 +127,6 @@ def ssd_chunk_scan(x: jax.Array, b: jax.Array, c: jax.Array,
         ],
         scratch_shapes=[pltpu.VMEM((hd, n), jnp.float32)],
         interpret=interpret,
-    )(a2, xc, bc, cc, dtc, state0)
+    )(xc, bc, cc, dtc, lc, lr, state0)
     y = y.reshape(bsz, h, t, hd).transpose(0, 2, 1, 3)
     return sf, y

@@ -30,6 +30,7 @@ the unified `python -m repro serve`.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 from pathlib import Path
 from typing import Optional, Sequence
@@ -102,15 +103,24 @@ def _serve_scheduler(args, cfg, model, params) -> int:
     for c in report.completions[:4]:
         print(f"req {c.rid}: {c.tokens}")
     print(report.summary())
-    print(f"(host wall {dt:.1f}s, {report.total_tokens / dt:.1f} tok/s "
-          f"on host CPU)")
+    print(f"(wall {dt:.1f}s, {report.total_tokens / dt:.1f} tok/s on "
+          f"{_device_label()})")
     return 0
+
+
+def _device_label() -> str:
+    dev = jax.devices()[0]
+    return f"{len(jax.devices())} x {dev.platform} {dev.device_kind}"
 
 
 def serve_main(argv: Optional[Sequence[str]] = None) -> int:
     ap = argparse.ArgumentParser(prog="python -m repro serve")
     ap.add_argument("--arch", choices=ARCH_IDS, required=True)
-    ap.add_argument("--reduced", action="store_true")
+    ap.add_argument("--reduced", action="store_true",
+                    help="tiny same-family widths for CPU smoke runs")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut depth only, to this many layers (published "
+                         "widths kept)")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--max-new", type=int, default=12)
     ap.add_argument("--max-batch", type=int, default=4)
@@ -158,8 +168,10 @@ def serve_main(argv: Optional[Sequence[str]] = None) -> int:
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
+    if args.layers is not None:
+        cfg = dataclasses.replace(cfg, n_layers=args.layers)
     model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    params = jax.jit(model.init)(jax.random.PRNGKey(0))
 
     if args.arrivals == "poisson":
         return _serve_scheduler(args, cfg, model, params)
@@ -193,7 +205,7 @@ def serve_main(argv: Optional[Sequence[str]] = None) -> int:
     for c in completions[:4]:
         print(f"req {c.rid}: {c.tokens}")
     print(f"{len(completions)} completions, {total_tokens} tokens "
-          f"in {dt:.1f}s ({total_tokens/dt:.1f} tok/s on host CPU)")
+          f"in {dt:.1f}s ({total_tokens/dt:.1f} tok/s on {_device_label()})")
 
     if compiled is not None:
         _, report = engine.execute_plan()

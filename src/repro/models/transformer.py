@@ -176,13 +176,16 @@ class TransformerModel:
             for k, kind in zip(jax.random.split(k_pro,
                                                 max(1, len(self.prologue))),
                                self.prologue)]
-        # pattern params: one stacked pytree per pattern position
+        # pattern params: one stacked pytree per pattern position, built
+        # stacked (vmap over the layer keys) so no per-layer copy exists
+        # beside the stack
         pat = []
         for i, kind in enumerate(self.pattern):
             keys = jax.random.split(jax.random.fold_in(k_pat, i),
                                     self.n_repeats)
-            per_layer = [init_block(k, cfg, kind, self.dtype) for k in keys]
-            pat.append(jax.tree.map(lambda *xs: jnp.stack(xs), *per_layer))
+            pat.append(jax.vmap(
+                lambda k, kind=kind: init_block(k, cfg, kind, self.dtype))(
+                    keys))
         params["pattern"] = pat
         return params
 

@@ -6,10 +6,16 @@ candidate in the kind's registry `TileSpec` grid (see
 `registry.TileSpec.configs`) against the op's actual kernel lowering and
 returns the fastest — by default searching only the numerics-preserving
 grid, whose candidates vary how the output space is tiled but keep every
-reduction-axis block at its default, so the winner computes bit-identical
-fp32 results to the default config.  `preserve_numerics=False` additionally
-searches reduction-axis blocks (bk / bs / chunk); those candidates are
-tolerance-exact, not bit-identical, and are never selected unless asked.
+reduction-axis block at its default, so the reduction blocks are summed in
+the default order.  The winner agrees with the default to fp32 rounding,
+not always bit for bit: a re-tiled matmul tile is computation, and the
+backend may order its sum by tile shape.  `preserve_numerics=False`
+additionally searches reduction-axis blocks (bk / bs / chunk), which
+reassociate the reduction itself; those are never selected unless asked.
+
+Measurements run the compiled kernels; ``interpret=True`` must be asked for
+(the Pallas interpreter's grid-step overhead, not kernel time, would pick
+the winner).
 
 Results persist in a content-addressed `TuneCache` with the same digest
 discipline as `runtime.cache.PlanCache`: the key digests the op codec, the
@@ -157,7 +163,7 @@ def _op_arrays(op: Op, seed: int = 0):
 
 
 def measure_tile_us(op: Op, tile: Optional[registry.TileConfig], *,
-                    reps: int = 2, interpret: bool = True,
+                    reps: int = 2, interpret: bool = False,
                     seed: int = 0) -> float:
     """Median wall (us) of the op's Pallas lowering under one config.
 
@@ -190,7 +196,7 @@ def autotune(op: Op, candidates: Optional[List[registry.TileConfig]] = None,
              *, cache: Optional[TuneCache] = None,
              device: str = "", backend: str = "",
              preserve_numerics: bool = True, reps: int = 2,
-             interpret: bool = True, seed: int = 0
+             interpret: bool = False, seed: int = 0
              ) -> registry.TileConfig:
     """Measured search over an op's legal tile-config grid.
 
@@ -241,7 +247,7 @@ def autotune(op: Op, candidates: Optional[List[registry.TileConfig]] = None,
 def annotate_plan_tiles(plan, *, cache: Optional[TuneCache] = None,
                         device: str = "", backend: str = "",
                         preserve_numerics: bool = True, reps: int = 2,
-                        interpret: bool = True):
+                        interpret: bool = False):
     """Attach autotuned tile configs to a plan's decisions, in place.
 
     The tune pass `compile(..., tune=True)` runs on a plan-cache miss

@@ -56,8 +56,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.coexec import (SplitPlan, coexec_conv2d, coexec_matmul,
-                               coexec_mesh, gather_stacked, mesh_groups,
+from jax.sharding import PartitionSpec as P
+
+from repro.core.coexec import (SplitPlan, cached_coexec_program,
+                               coexec_conv2d, coexec_matmul, coexec_mesh,
+                               gather_stacked, mesh_fingerprint, mesh_groups,
                                pack_weights, split_for_mesh)
 from repro.core.networks import Unit, pool_out_edge
 from repro.graph.ir import Graph
@@ -353,10 +356,29 @@ class PlanExecutor:
                ) -> jax.Array:
         """Unsplit execution through the registry lowering."""
         low = registry.get_lowering(spec.unit)
-        if self.use_pallas:
-            return low.pallas(x, w, spec.op, interpret=self.interpret,
+        if not self.use_pallas:
+            return low.oracle(x, w, spec.op)
+
+        # bound here so a cached program does not hold the executor and its
+        # weights
+        interpret = self.interpret
+
+        def kernel(x, w):
+            return low.pallas(x, w, spec.op, interpret=interpret,
                               tile=spec.tile)
-        return low.oracle(x, w, spec.op)
+
+        if self.mesh.size == 1:
+            return kernel(x, w)
+        # a Pallas kernel is never partitioned automatically: on a
+        # multi-device mesh every device runs the whole op on replicated
+        # operands, as XLA runs the oracle (check_vma=False: a kernel's
+        # output type carries no varying-axis annotation)
+        key = ("dense", spec.unit, spec.op, spec.tile, interpret,
+               mesh_fingerprint(self.mesh), tuple(x.shape), str(x.dtype),
+               tuple(w.shape), str(w.dtype))
+        return cached_coexec_program(key, lambda: jax.shard_map(
+            kernel, mesh=self.mesh, in_specs=(P(), P()), out_specs=P(),
+            check_vma=False))(x, w)
 
     def _chains(self, act: _Stacked, spec: ExecSpec) -> bool:
         """Can this unit consume the producer's stack directly?  Only when

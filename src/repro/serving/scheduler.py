@@ -250,7 +250,9 @@ class ContinuousScheduler:
         self.plan_cache = plan_cache   # replanned plans land here (None =
         #                                the compile-time default cache dir)
         self.rng = jax.random.PRNGKey(self.config.seed)
-        self._decode = jax.jit(model.decode_step)
+        # the cache is donated: each step's output replaces it, so one KV
+        # cache is live at a time, not two
+        self._decode = jax.jit(model.decode_step, donate_argnums=(2,))
         # per-bucket drift state (portfolio mode)
         self._monitors: Dict[Any, Any] = {}
         self._recent_reports: Dict[Any, List[Any]] = {}

@@ -219,8 +219,12 @@ _SPLIT_PROG = textwrap.dedent("""
         np.testing.assert_allclose(y, ref, rtol=1e-5, atol=1e-6)
     print("KV_BLOCK_OK")
 
-    # ---- executor level: planned typed-axis schedule, fused AND unfused,
-    # bit-identical to the unsplit per-node oracle walk
+    # ---- executor level: planned typed-axis schedule, fused AND unfused.
+    # Both walks run the same split programs, so they agree bit-for-bit.
+    # Against the unsplit per-node oracle they agree to fp32 rounding: the
+    # channel-split linear nodes are 1-row matmuls, which XLA:CPU reduces
+    # in an order that depends on the output width, so a column slice of
+    # x @ w is not bit-identical to those columns of the full product
     def forced(g):
         decisions, opaque = {}, {}
         for n in g:
@@ -267,8 +271,9 @@ _SPLIT_PROG = textwrap.dedent("""
         y_u, rep_u = exe.run(chain=True)
         y_f, rep_f = exe.run(fused=True)
         y_o = exe.run_oracle()
-        assert np.asarray(y_u).tobytes() == np.asarray(y_o).tobytes(), name
-        assert np.asarray(y_f).tobytes() == np.asarray(y_o).tobytes(), name
+        assert np.asarray(y_f).tobytes() == np.asarray(y_u).tobytes(), name
+        np.testing.assert_allclose(np.asarray(y_u), np.asarray(y_o),
+                                   rtol=1e-5, atol=1e-5, err_msg=name)
         assert rep_u.count("coexec") > 0, name
         print(name, "exec ok:", len(typed), "typed-axis node(s)")
     print("DECODE_EXEC_OK")

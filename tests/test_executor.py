@@ -303,6 +303,20 @@ _SUBPROCESS_PROG = textwrap.dedent("""
             splits.append((op.C_out - op.C_out // 4, op.C_out // 4))
     check(tail, splits, "resnet18-tail")
     print("SPLIT_EXEC_OK")
+
+    # Pallas kernels of exclusive nodes on the split mesh run replicated
+    # under shard_map (the TPU partitions no kernel automatically)
+    splits[-1] = (ops[-1].C_out, 0)              # the classifier, exclusive
+    plan = forced_plan(tail, splits)
+    ref = PlanExecutor(plan, mesh=mesh).run_oracle()
+    exe = PlanExecutor(plan, mesh=mesh, use_pallas=True, interpret=True)
+    y_u, rep_u = exe.run()
+    y_f, _ = exe.run(fused=True)
+    assert rep_u.count("exclusive") > 0 and rep_u.count("coexec") > 0
+    for y in (y_u, y_f):
+        np.testing.assert_allclose(np.asarray(y), np.asarray(ref),
+                                   rtol=2e-5, atol=2e-5)
+    print("PALLAS_ON_SPLIT_MESH_OK")
 """)
 
 
@@ -313,3 +327,4 @@ def test_split_execution_and_gather_elision_on_8_virtual_devices():
                          capture_output=True, text=True, timeout=600)
     assert out.returncode == 0, out.stderr[-3000:]
     assert "SPLIT_EXEC_OK" in out.stdout
+    assert "PALLAS_ON_SPLIT_MESH_OK" in out.stdout

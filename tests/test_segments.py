@@ -215,7 +215,7 @@ def _residual_graph(rng, n_blocks: int, exclusive_mid: bool
     return Graph(nodes)
 
 
-@settings(max_examples=8)
+@settings(max_examples=8, deadline=None)
 @given(seed=st.integers(0, 10 ** 6), n_blocks=st.integers(1, 3),
        dtype=st.sampled_from(["float32", "bfloat16"]),
        exclusive_mid=st.sampled_from([False, True]))
