@@ -1,0 +1,20 @@
+"""The split_matmul kernel's share of its roofline: the least time the
+chip could take for the network's projections (the larger of operations
+over peak and bytes over bandwidth, from the configuration's shapes), over
+the summed device time of the kernel's events (profiler trace)."""
+from counts import roofline_s
+
+#: the kernel's custom call inside its jitted wrapper
+PROGRAM, KERNEL = r"split_matmul_op", r"tpu_custom_call"
+
+
+def read(ctx):
+    tr, raw = ctx["trace"], ctx["raw"]
+    if tr is None or "ops" not in raw:
+        return None
+    t = tr.kernel_ns(PROGRAM, KERNEL, ctx["window"]) / 1e9
+    if t <= 0.0:
+        return None
+    bound = sum(roofline_s(op, ctx["peak"]) for op in raw["ops"]
+                if op["kind"] == "linear") * raw["n"]
+    return 100.0 * bound / t

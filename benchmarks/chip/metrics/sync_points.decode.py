@@ -1,0 +1,6 @@
+"""Device syncs the executor issues per decode step of the block graph
+(`ExecutionReport.sync_points` of the window's last run)."""
+
+
+def read(ctx):
+    return ctx["raw"].get("sync_points")
