@@ -120,5 +120,6 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((g_pad, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="decode_attention",
     )(pos_arr, qg, kt, vt)
     return out[:, :g, :].reshape(h, hd)

@@ -85,6 +85,7 @@ def split_matmul(x: jax.Array, w: jax.Array, c0: int, width: int, *,
         out_shape=jax.ShapeDtypeStruct((mp, np_), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="split_matmul",
     )(x, w_slice)
     return out[:m, :width]
 

@@ -127,6 +127,7 @@ def ssd_chunk_scan(x: jax.Array, b: jax.Array, c: jax.Array,
         ],
         scratch_shapes=[pltpu.VMEM((hd, n), jnp.float32)],
         interpret=interpret,
+        name="ssd_chunk",
     )(xc, bc, cc, dtc, lc, lr, state0)
     y = y.reshape(bsz, h, t, hd).transpose(0, 2, 1, 3)
     return sf, y

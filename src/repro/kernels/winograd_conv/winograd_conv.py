@@ -84,6 +84,7 @@ def hadamard_matmul(u: jax.Array, v: jax.Array, *, bm: int = None,
         out_shape=jax.ShapeDtypeStruct((g, u.shape[1], v.shape[2]), u.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="winograd_conv",
     )(u, v)
     return out[:, :p, :n]
 

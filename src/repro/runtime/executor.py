@@ -43,13 +43,20 @@ plan's predicted latency per op (what `MeasurementStore`/`Calibrator`
 consume for online replanning).  Note the predictions model a *phone*, the
 execution runs on *this host* — the report tracks the ratio's stability
 across ops, not its absolute value.
+
+The timings are read from the walk's spans (`repro.measure.trace`), which
+a profiler session also records on its clock: `repro.exec.run` bounds one
+walk; `repro.exec.segment` one fused segment (one node in the per-node
+walk), from the call that enqueues it to the end of its sync; and
+`repro.exec.sync` each `block_until_ready` — inside its segment, and for
+the per-node walk's terminal gather, directly inside the run.  A
+segment's time less its sync is the host's dispatch time.
 """
 from __future__ import annotations
 
 import dataclasses
 import math
 import platform
-import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
@@ -65,6 +72,7 @@ from repro.core.coexec import (SplitPlan, cached_coexec_program,
 from repro.core.networks import Unit, pool_out_edge
 from repro.graph.ir import Graph
 from repro.kernels import registry
+from repro.measure import trace
 from repro.measure.record import (SOURCE_EXECUTOR, SOURCE_FUSED,
                                   MeasurementRecord, usable_for_fidelity)
 from repro.runtime.plan import (CoexecPlan, ExecSpec, network_fingerprint,
@@ -432,8 +440,12 @@ class PlanExecutor:
             raise ValueError(
                 "fused=True implies chaining — chain=False is the "
                 "gather-every-op reference walk and has no fused form")
-        step = (lambda: self._execute_fused(x)) if fused else (
-            lambda: self._execute(x, chain=chain))
+        def step():
+            with trace.span("repro.exec.run"):
+                if fused:
+                    return self._execute_fused(x)
+                return self._execute(x, chain=chain)
+
         key = (chain, fused)
         if warmup and key not in self._warmed:
             step()                               # untimed: not published
@@ -474,87 +486,88 @@ class PlanExecutor:
         for i, (node, spec) in enumerate(zip(self.graph, self.specs)):
             w = self.params[i]
             src = node.inputs[0] if node.inputs else None
-            t0 = time.perf_counter()
-            chained = False
-            if spec.unit == "pool":
-                mode = "pool"
-                out = self._pool(materialized(src), spec.pool_bytes)
-            elif spec.unit == "add":
-                mode = "add"
-                parts = [materialized(s) for s in node.inputs]
-                shapes = {tuple(p.shape) for p in parts}
-                if len(shapes) != 1:
-                    raise ValueError(
-                        f"add node {node.id!r} joins mismatched shapes "
-                        f"{sorted(shapes)}")
-                out = parts[0]
-                for p in parts[1:]:
-                    out = out + p
+            do_split = self.split_capable and spec.coexec
+            if spec.unit in ("pool", "add"):
+                mode = spec.unit
             else:
-                do_split = self.split_capable and spec.coexec
-                x_plan = None
-                prod_act = x0 if src is None else acts[src]
-                # gather-elision as a graph property: consume the
-                # producer's group-local stack iff we are its SOLE
-                # consumer, we split too, and the shapes chain exactly
-                if (isinstance(prod_act, _Stacked) and chain and do_split
-                        and self._chains(prod_act, spec)
-                        and len(self.graph.consumers(src)) == 1):
-                    x_in, x_plan = prod_act.data, prod_act.split
-                    chained = True
-                    elided += 1
+                mode = "coexec" if do_split else "exclusive"
+            chained = False
+            with trace.span("repro.exec.segment", index=i, mode=mode) as seg:
+                if mode == "pool":
+                    out = self._pool(materialized(src), spec.pool_bytes)
+                elif mode == "add":
+                    parts = [materialized(s) for s in node.inputs]
+                    shapes = {tuple(p.shape) for p in parts}
+                    if len(shapes) != 1:
+                        raise ValueError(
+                            f"add node {node.id!r} joins mismatched shapes "
+                            f"{sorted(shapes)}")
+                    out = parts[0]
+                    for p in parts[1:]:
+                        out = out + p
                 else:
-                    x_in = self._adapt(materialized(src), spec)
-                if do_split:
-                    mode = "coexec"
-                    op = spec.op
-                    split, packed = self._splits[i]
-                    if spec.unit == "linear":
-                        y = coexec_matmul(x_in, packed, split, self.mesh,
-                                          gather=False, x_plan=x_plan)
-                        out = _Stacked(y, split, (op.L, op.C_out))
-                    elif spec.unit == "conv":
-                        y = coexec_conv2d(x_in, packed, split, self.mesh,
-                                          stride=op.S, gather=False,
-                                          x_plan=x_plan)
-                        # SAME conv rounds up; crop the stack to the
-                        # declared (floor) shape so chaining stays exact
-                        y = y[:, :, :op.H_out, :op.W_out, :]
-                        b = x_in.shape[1] if chained else x_in.shape[0]
-                        out = _Stacked(y, split,
-                                       (b, op.H_out, op.W_out, op.C_out))
-                    else:       # typed axis: registered split lowering
-                        low = registry.get_split_lowering(spec.unit,
-                                                          spec.axis)
-                        y = low.run(x_in, packed, split, self.mesh, op,
-                                    spec.c_fast, gather=False,
-                                    x_plan=x_plan,
-                                    use_pallas=self.use_pallas,
-                                    interpret=self.interpret,
-                                    tile=spec.tile)
-                        if spec.axis == "kv-block":
-                            # non-stackable: the lowering merged its
-                            # softmax partials and materialized internally
-                            out = y
-                        else:
-                            shape = tuple(registry.get(
-                                spec.unit).output_shape(op))
-                            out = _Stacked(y, split, shape)
-                    if isinstance(out, _Stacked) and not chain:
-                        out, r = self._materialize(out)  # sync every op
-                        reshard += r
-                else:
-                    mode = "exclusive"
-                    out = self._dense(x_in, w, spec)
-            acts[node.id] = out
-            jax.block_until_ready(out.data if isinstance(out, _Stacked)
-                                  else out)
+                    x_plan = None
+                    prod_act = x0 if src is None else acts[src]
+                    # gather-elision as a graph property: consume the
+                    # producer's group-local stack iff we are its SOLE
+                    # consumer, we split too, and the shapes chain exactly
+                    if (isinstance(prod_act, _Stacked) and chain and do_split
+                            and self._chains(prod_act, spec)
+                            and len(self.graph.consumers(src)) == 1):
+                        x_in, x_plan = prod_act.data, prod_act.split
+                        chained = True
+                        elided += 1
+                    else:
+                        x_in = self._adapt(materialized(src), spec)
+                    if do_split:
+                        op = spec.op
+                        split, packed = self._splits[i]
+                        if spec.unit == "linear":
+                            y = coexec_matmul(x_in, packed, split, self.mesh,
+                                              gather=False, x_plan=x_plan)
+                            out = _Stacked(y, split, (op.L, op.C_out))
+                        elif spec.unit == "conv":
+                            y = coexec_conv2d(x_in, packed, split, self.mesh,
+                                              stride=op.S, gather=False,
+                                              x_plan=x_plan)
+                            # SAME conv rounds up; crop the stack to the
+                            # declared (floor) shape so chaining stays exact
+                            y = y[:, :, :op.H_out, :op.W_out, :]
+                            b = x_in.shape[1] if chained else x_in.shape[0]
+                            out = _Stacked(y, split,
+                                           (b, op.H_out, op.W_out, op.C_out))
+                        else:       # typed axis: registered split lowering
+                            low = registry.get_split_lowering(spec.unit,
+                                                              spec.axis)
+                            y = low.run(x_in, packed, split, self.mesh, op,
+                                        spec.c_fast, gather=False,
+                                        x_plan=x_plan,
+                                        use_pallas=self.use_pallas,
+                                        interpret=self.interpret,
+                                        tile=spec.tile)
+                            if spec.axis == "kv-block":
+                                # non-stackable: the lowering merged its
+                                # softmax partials and materialized internally
+                                out = y
+                            else:
+                                shape = tuple(registry.get(
+                                    spec.unit).output_shape(op))
+                                out = _Stacked(y, split, shape)
+                        if isinstance(out, _Stacked) and not chain:
+                            out, r = self._materialize(out)  # sync every op
+                            reshard += r
+                    else:
+                        out = self._dense(x_in, w, spec)
+                acts[node.id] = out
+                with trace.span("repro.exec.sync"):
+                    jax.block_until_ready(out.data if isinstance(out, _Stacked)
+                                          else out)
             timings.append(MeasurementRecord(
                 index=i, unit=spec.unit, label=spec_label(spec), mode=mode,
                 c_fast=spec.c_fast, c_slow=spec.c_slow,
                 chained_input=chained,
                 gathered_output=not isinstance(out, _Stacked),
-                wall_us=(time.perf_counter() - t0) * 1e6,
+                wall_us=seg.elapsed_s * 1e6,
                 pred_us=spec.pred_total_us,
                 op=spec.op, source=SOURCE_EXECUTOR, device=prov.device,
                 host=host, plan_key=self.plan.key,
@@ -569,13 +582,13 @@ class PlanExecutor:
         # the terminal sync point: with chaining, the last co-executed op's
         # gather is deferred to here — time it and charge it to that op so
         # chained and gather-every-op wall totals stay comparable
-        t0 = time.perf_counter()
-        y, r = self._materialize(acts[self.graph.output.id])
-        jax.block_until_ready(y)
+        with trace.span("repro.exec.sync") as sync:
+            y, r = self._materialize(acts[self.graph.output.id])
+            jax.block_until_ready(y)
         reshard += r
         if timings and r:
             timings[-1].gathered_output = True
-            timings[-1].wall_us += (time.perf_counter() - t0) * 1e6
+            timings[-1].wall_us += sync.elapsed_s * 1e6
         report = ExecutionReport(
             device=prov.device,
             network_fingerprint=prov.network_fingerprint,
@@ -611,32 +624,37 @@ class PlanExecutor:
         prov = self.plan.provenance
 
         for sp in programs:
-            t0 = time.perf_counter()
-            if sp.fn is not None:
-                out = sp.fn([acts[s] for s in sp.ext_inputs], sp.weights)
-            else:
-                nid = sp.node_ids[0]
-                spec = self.specs[pos[nid]]
-                src_val = acts[sp.ext_inputs[0]]
-                if sp.modes[nid] == "pool":
-                    out = self._pool(src_val, spec.pool_bytes)
-                elif sp.modes[nid] == "coexec":
-                    # typed-axis split: runs as an eager exclusive-segment
-                    # singleton so its shard_map program is the sole
-                    # compilation unit (fp32 bit-identity vs the oracle);
-                    # kv-block additionally merges/materializes internally
-                    split, packed = self._splits[pos[nid]]
-                    low = registry.get_split_lowering(spec.unit, spec.axis)
-                    out = low.run(self._adapt(src_val, spec), packed,
-                                  split, self.mesh, spec.op, spec.c_fast,
-                                  use_pallas=self.use_pallas,
-                                  interpret=self.interpret,
-                                  tile=spec.tile)
+            mode = "fused" if sp.fn is not None else sp.modes[sp.node_ids[0]]
+            with trace.span("repro.exec.segment", index=sp.index,
+                            mode=mode) as seg:
+                if sp.fn is not None:
+                    out = sp.fn([acts[s] for s in sp.ext_inputs], sp.weights)
                 else:
-                    out = self._dense(self._adapt(src_val, spec),
-                                      self.params[pos[nid]], spec)
-            jax.block_until_ready(out)
-            wall = (time.perf_counter() - t0) * 1e6
+                    nid = sp.node_ids[0]
+                    spec = self.specs[pos[nid]]
+                    src_val = acts[sp.ext_inputs[0]]
+                    if mode == "pool":
+                        out = self._pool(src_val, spec.pool_bytes)
+                    elif mode == "coexec":
+                        # typed-axis split: runs as an eager exclusive-
+                        # segment singleton so its shard_map program is the
+                        # sole compilation unit (fp32 bit-identity vs the
+                        # oracle); kv-block additionally merges/materializes
+                        # internally
+                        split, packed = self._splits[pos[nid]]
+                        low = registry.get_split_lowering(spec.unit,
+                                                          spec.axis)
+                        out = low.run(self._adapt(src_val, spec), packed,
+                                      split, self.mesh, spec.op, spec.c_fast,
+                                      use_pallas=self.use_pallas,
+                                      interpret=self.interpret,
+                                      tile=spec.tile)
+                    else:
+                        out = self._dense(self._adapt(src_val, spec),
+                                          self.params[pos[nid]], spec)
+                with trace.span("repro.exec.sync"):
+                    jax.block_until_ready(out)
+            wall = seg.elapsed_s * 1e6
             segment_wall.append(wall)
             reshard += sp.gathers
             elided += sp.elided

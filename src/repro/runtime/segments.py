@@ -204,7 +204,7 @@ def compile_segments(exe, x_shape: Tuple[int, ...]) -> List[SegmentProgram]:
             index=k, kind=SEGMENT_FUSED, node_ids=seg.node_ids,
             ext_inputs=tuple(ext), gathers=gathers, elided=elided,
             chained=chained_f, gathered=gathered_f, modes=modes,
-            fn=_emit(exe, instrs, tuple(ext)), weights=weights))
+            fn=_emit(exe, k, instrs, tuple(ext)), weights=weights))
     return programs
 
 
@@ -239,7 +239,7 @@ def _layout_singleton(exe, index: int, seg, plain_shape) -> SegmentProgram:
         gathered={nid: True}, modes={nid: mode})
 
 
-def _emit(exe, instrs: List[Dict[str, Any]],
+def _emit(exe, index: int, instrs: List[Dict[str, Any]],
           ext_keys: Tuple[Optional[str], ...]) -> Callable:
     """Close the instruction list into one jitted program.
 
@@ -247,6 +247,10 @@ def _emit(exe, instrs: List[Dict[str, Any]],
     where `ext_vals` follows `ext_keys` and `weights` the instruction
     slots — both traced arguments, so no activation or parameter is ever
     baked into the compiled computation as a constant.
+
+    The program is named `segment_<index>` (its device module is
+    `jit_segment_<index>`), and each member's ops sit under a
+    `jax.named_scope` of its node id, so a profile names both.
     """
     from repro.runtime.executor import _Stacked
     mesh = exe.mesh
@@ -263,46 +267,48 @@ def _emit(exe, instrs: List[Dict[str, Any]],
             return v
 
         for ins in instrs:
-            if ins["kind"] == "add":
-                parts = [plain(s) for s in ins["srcs"]]
-                out = parts[0]
-                for p in parts[1:]:
-                    out = out + p
-            else:
-                spec = ins["spec"]
-                op = spec.op
-                if ins["mode"] == "coexec":
-                    if ins["chained"]:
-                        prod = env[ins["src"]]
-                        x_in, x_plan = prod.data, prod.split
-                    else:
-                        x_in = exe._adapt(plain(ins["src"]), spec)
-                        x_plan = None
-                    split = ins["split"]
-                    packed = weights[ins["slot"]]
-                    if spec.unit == "linear":
-                        y = coexec_matmul(x_in, packed, split, mesh,
-                                          gather=False, x_plan=x_plan)
-                    elif spec.unit == "conv":
-                        y = coexec_conv2d(x_in, packed, split, mesh,
-                                          stride=op.S, gather=False,
-                                          x_plan=x_plan)
-                        # SAME conv rounds up; crop to the declared shape
-                        y = y[:, :, :op.H_out, :op.W_out, :]
-                    else:    # head-/state-split attention, ssm
-                        low = registry.get_split_lowering(spec.unit,
-                                                          spec.axis)
-                        y = low.run(x_in, packed, split, mesh, op,
-                                    spec.c_fast, gather=False,
-                                    x_plan=x_plan,
-                                    use_pallas=exe.use_pallas,
-                                    interpret=exe.interpret,
-                                    tile=spec.tile)
-                    out = _Stacked(y, split, ins["shape"])
-                else:
-                    out = exe._dense(exe._adapt(plain(ins["src"]), spec),
-                                     weights[ins["slot"]], spec)
-            env[ins["id"]] = out
+            with jax.named_scope(ins["id"]):
+                env[ins["id"]] = _member(exe, ins, env, plain, weights)
         return plain(instrs[-1]["id"])
 
+    program.__name__ = program.__qualname__ = f"segment_{index}"
     return jax.jit(program)
+
+
+def _member(exe, ins: Dict[str, Any], env, plain, weights):
+    """One segment member's ops over traced values (see `_emit`)."""
+    from repro.runtime.executor import _Stacked
+    mesh = exe.mesh
+    if ins["kind"] == "add":
+        parts = [plain(s) for s in ins["srcs"]]
+        out = parts[0]
+        for p in parts[1:]:
+            out = out + p
+        return out
+    spec = ins["spec"]
+    op = spec.op
+    if ins["mode"] != "coexec":
+        return exe._dense(exe._adapt(plain(ins["src"]), spec),
+                          weights[ins["slot"]], spec)
+    if ins["chained"]:
+        prod = env[ins["src"]]
+        x_in, x_plan = prod.data, prod.split
+    else:
+        x_in = exe._adapt(plain(ins["src"]), spec)
+        x_plan = None
+    split = ins["split"]
+    packed = weights[ins["slot"]]
+    if spec.unit == "linear":
+        y = coexec_matmul(x_in, packed, split, mesh, gather=False,
+                          x_plan=x_plan)
+    elif spec.unit == "conv":
+        y = coexec_conv2d(x_in, packed, split, mesh, stride=op.S,
+                          gather=False, x_plan=x_plan)
+        # SAME conv rounds up; crop to the declared shape
+        y = y[:, :, :op.H_out, :op.W_out, :]
+    else:    # head-/state-split attention, ssm
+        low = registry.get_split_lowering(spec.unit, spec.axis)
+        y = low.run(x_in, packed, split, mesh, op, spec.c_fast,
+                    gather=False, x_plan=x_plan, use_pallas=exe.use_pallas,
+                    interpret=exe.interpret, tile=spec.tile)
+    return _Stacked(y, split, ins["shape"])

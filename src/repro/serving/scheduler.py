@@ -34,6 +34,14 @@ compares scheduler-vs-fixed-batch under; `clock="wall"` uses the host
 stopwatch.  `FixedBatchReference` replays the fixed-batch engine's
 admission/batching semantics under the same virtual clock and a single
 plan — the baseline the portfolio scheduler must beat.
+
+Spans (`repro.measure.trace`, recorded under a profiler session): each
+step is a `repro.sched.step`; inside it `repro.sched.inputs` (building
+the step's tokens/positions/temperatures and handing them to the
+device), `repro.sched.decode` (the jitted decode call),
+`repro.sched.sample` (`sample_tokens`), `repro.sched.emit` (per-row token
+reads and slot bookkeeping) and, with a portfolio, `repro.sched.fidelity`.
+Admission and bucket selection are the step's self time.
 """
 from __future__ import annotations
 
@@ -43,6 +51,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro.measure import trace
 from repro.serving.engine import Completion, Request
 
 #: per-step cost (seconds) charged by the virtual clock when no portfolio
@@ -100,11 +109,22 @@ class ReplanEvent:
 
 @dataclasses.dataclass
 class RequestStats:
+    """One finished request on the scheduler clock.
+
+    `admitted_s` is when it took a slot.  `token_s[k]` stamps its k-th
+    output token: the scheduler clock after the step's sampling is
+    dispatched, before the token is read back to the host (a change that
+    moves the read must move the stamp with it).  `first_token_s` equals
+    `token_s[0]`.
+    """
+
     rid: int
     arrival_s: float
     first_token_s: float
     done_s: float
     n_tokens: int
+    admitted_s: Optional[float] = None
+    token_s: List[float] = dataclasses.field(default_factory=list)
 
     @property
     def ttft_s(self) -> float:
@@ -114,10 +134,22 @@ class RequestStats:
     def latency_s(self) -> float:
         return self.done_s - self.arrival_s
 
+    @property
+    def queue_wait_s(self) -> Optional[float]:
+        if self.admitted_s is None:
+            return None
+        return self.admitted_s - self.arrival_s
+
+    @property
+    def itl_s(self) -> List[float]:
+        """Gaps between consecutive output tokens."""
+        return [b - a for a, b in zip(self.token_s, self.token_s[1:])]
+
     def to_json(self) -> Dict[str, Any]:
         d = dataclasses.asdict(self)
         d["ttft_s"] = self.ttft_s
         d["latency_s"] = self.latency_s
+        d["queue_wait_s"] = self.queue_wait_s
         return d
 
 
@@ -150,6 +182,16 @@ class SchedulerReport:
     def ttft_p(self, q: float) -> float:
         return _percentile([s.ttft_s for s in self.stats], q)
 
+    def queue_wait_p(self, q: float) -> float:
+        """Percentile of arrival-to-admission time over requests."""
+        return _percentile([s.queue_wait_s for s in self.stats
+                            if s.queue_wait_s is not None], q)
+
+    def itl_p(self, q: float) -> float:
+        """Percentile over every gap between consecutive output tokens of
+        a request, over all requests."""
+        return _percentile([g for s in self.stats for g in s.itl_s], q)
+
     def to_json(self) -> Dict[str, Any]:
         return {
             "clock": self.clock,
@@ -162,6 +204,10 @@ class SchedulerReport:
             "latency_p99_s": self.latency_p(99),
             "ttft_p50_s": self.ttft_p(50),
             "ttft_p99_s": self.ttft_p(99),
+            "queue_wait_p50_s": self.queue_wait_p(50),
+            "queue_wait_p99_s": self.queue_wait_p(99),
+            "itl_p50_s": self.itl_p(50),
+            "itl_p99_s": self.itl_p(99),
             "bucket_switches": self.bucket_switches,
             "bucket_steps": dict(self.bucket_steps),
             "replan_events": [e.to_json() for e in self.replan_events],
@@ -175,6 +221,9 @@ class SchedulerReport:
             f"  latency p50 {self.latency_p(50):.3f}s  "
             f"p99 {self.latency_p(99):.3f}s | ttft p50 "
             f"{self.ttft_p(50):.3f}s  p99 {self.ttft_p(99):.3f}s",
+            f"  queue wait p50 {self.queue_wait_p(50):.3f}s  "
+            f"p99 {self.queue_wait_p(99):.3f}s | itl p50 "
+            f"{self.itl_p(50):.4f}s  p99 {self.itl_p(99):.4f}s",
         ]
         if self.bucket_steps:
             per = " ".join(f"{tag}:{n}" for tag, n in
@@ -195,7 +244,8 @@ class SchedulerReport:
 class _Slot:
     """One in-flight request bound to a batch row."""
 
-    __slots__ = ("req", "pos", "out", "cur", "admitted_s", "first_token_s")
+    __slots__ = ("req", "pos", "out", "cur", "admitted_s", "first_token_s",
+                 "token_s")
 
     def __init__(self, req: Request, now: float):
         self.req = req
@@ -204,6 +254,7 @@ class _Slot:
         self.cur: Optional[int] = None  # last sampled token
         self.admitted_s = now
         self.first_token_s: Optional[float] = None
+        self.token_s: List[float] = []  # stamp of each output token
 
     @property
     def prefilling(self) -> bool:
@@ -397,85 +448,96 @@ class ContinuousScheduler:
         wall_anchor = time.perf_counter()
 
         while pending or any(s is not None for s in slots):
-            # ---------------------------------------------------- admission
-            if all(s is None for s in slots) and pending and \
-                    pending[-1].arrival_s > now:
-                now = pending[-1].arrival_s    # idle: fast-forward
-            for i in range(cfg.max_batch):
-                if slots[i] is None and pending and \
-                        pending[-1].arrival_s <= now:
-                    slots[i] = _Slot(pending.pop(), now)
-            active = [i for i, s in enumerate(slots) if s is not None]
-            if not active:
-                continue
-
-            # ---------------------------------------------- bucket selection
-            bucket, compiled = None, None
-            if self.portfolio is not None:
-                live_b = len(active)
-                live_seq = max(slots[i].pos + 1 for i in active)
-                bucket, compiled = self.portfolio.select(live_b, live_seq)
-                tag = bucket.tag
-                bucket_steps[tag] = bucket_steps.get(tag, 0) + 1
-                if last_bucket is not None and bucket != last_bucket:
-                    bucket_switches += 1
-                last_bucket = bucket
-
-            # ------------------------------------------------- decode step
-            toks = np.zeros((cfg.max_batch, 1), np.int32)
-            pos = np.zeros((cfg.max_batch,), np.int32)
-            temps = np.zeros((cfg.max_batch,), np.float32)
-            for i in active:
-                s = slots[i]
-                if s.prefilling:
-                    toks[i, 0] = int(s.req.prompt[s.pos])
-                else:
-                    toks[i, 0] = s.cur
-                    temps[i] = s.req.temperature
-                pos[i] = s.pos
-            logits, cache = self._decode(self.params, jnp.asarray(toks),
-                                         cache, jnp.asarray(pos))
-            # sampling temperature applies only to rows past their prompt;
-            # rows mid-prefill (and free rows) stay greedy so they never
-            # consume rng — admission order cannot shift another request's
-            # sampled tokens
-            sampled, self.rng = sample_tokens(self.rng, logits, temps)
-            steps += 1
-
-            # ----------------------------------------------------- advance
-            if cfg.clock == "virtual":
-                if compiled is not None and \
-                        compiled.plan.end_to_end_us is not None:
-                    now += compiled.plan.end_to_end_us * 1e-6
-                else:
-                    now += DEFAULT_STEP_COST_S
-            else:
-                t1 = time.perf_counter()
-                now += t1 - wall_anchor
-                wall_anchor = t1
-
-            for i in active:
-                s = slots[i]
-                emits = s.pos >= len(s.req.prompt) - 1   # last prompt tok
-                s.pos += 1
-                if not emits:
+            with trace.span("repro.sched.step", step=steps) as step_span:
+                # ------------------------------------------------ admission
+                if all(s is None for s in slots) and pending and \
+                        pending[-1].arrival_s > now:
+                    now = pending[-1].arrival_s    # idle: fast-forward
+                for i in range(cfg.max_batch):
+                    if slots[i] is None and pending and \
+                            pending[-1].arrival_s <= now:
+                        slots[i] = _Slot(pending.pop(), now)
+                active = [i for i, s in enumerate(slots) if s is not None]
+                if not active:
                     continue
-                s.cur = int(sampled[i])
-                s.out.append(s.cur)
-                total_tokens += 1
-                if s.first_token_s is None:
-                    s.first_token_s = now
-                if s.done:
-                    completions.append(Completion(s.req.rid, s.out))
-                    stats.append(RequestStats(
-                        rid=s.req.rid, arrival_s=s.req.arrival_s,
-                        first_token_s=s.first_token_s, done_s=now,
-                        n_tokens=len(s.out)))
-                    slots[i] = None
+                step_span.set(active=len(active))
 
-            # ---------------------------------------------------- fidelity
-            if compiled is not None and steps % cfg.fidelity_every == 0:
-                self._observe_fidelity(bucket, compiled, now, steps)
+                # ---------------------------------------- bucket selection
+                bucket, compiled = None, None
+                if self.portfolio is not None:
+                    live_b = len(active)
+                    live_seq = max(slots[i].pos + 1 for i in active)
+                    bucket, compiled = self.portfolio.select(live_b,
+                                                             live_seq)
+                    tag = bucket.tag
+                    bucket_steps[tag] = bucket_steps.get(tag, 0) + 1
+                    if last_bucket is not None and bucket != last_bucket:
+                        bucket_switches += 1
+                    last_bucket = bucket
+
+                # --------------------------------------------- decode step
+                with trace.span("repro.sched.inputs"):
+                    toks = np.zeros((cfg.max_batch, 1), np.int32)
+                    pos = np.zeros((cfg.max_batch,), np.int32)
+                    temps = np.zeros((cfg.max_batch,), np.float32)
+                    for i in active:
+                        s = slots[i]
+                        if s.prefilling:
+                            toks[i, 0] = int(s.req.prompt[s.pos])
+                        else:
+                            toks[i, 0] = s.cur
+                            temps[i] = s.req.temperature
+                        pos[i] = s.pos
+                    toks_d, pos_d = jnp.asarray(toks), jnp.asarray(pos)
+                with trace.span("repro.sched.decode"):
+                    logits, cache = self._decode(self.params, toks_d, cache,
+                                                 pos_d)
+                # sampling temperature applies only to rows past their
+                # prompt; rows mid-prefill (and free rows) stay greedy so
+                # they never consume rng — admission order cannot shift
+                # another request's sampled tokens
+                with trace.span("repro.sched.sample"):
+                    sampled, self.rng = sample_tokens(self.rng, logits, temps)
+                steps += 1
+
+                # ------------------------------------------------- advance
+                if cfg.clock == "virtual":
+                    if compiled is not None and \
+                            compiled.plan.end_to_end_us is not None:
+                        now += compiled.plan.end_to_end_us * 1e-6
+                    else:
+                        now += DEFAULT_STEP_COST_S
+                else:
+                    t1 = time.perf_counter()
+                    now += t1 - wall_anchor
+                    wall_anchor = t1
+
+                with trace.span("repro.sched.emit"):
+                    for i in active:
+                        s = slots[i]
+                        emits = s.pos >= len(s.req.prompt) - 1  # last prompt
+                        s.pos += 1
+                        if not emits:
+                            continue
+                        s.cur = int(sampled[i])
+                        s.out.append(s.cur)
+                        s.token_s.append(now)
+                        total_tokens += 1
+                        if s.first_token_s is None:
+                            s.first_token_s = now
+                        if s.done:
+                            completions.append(Completion(s.req.rid, s.out))
+                            stats.append(RequestStats(
+                                rid=s.req.rid, arrival_s=s.req.arrival_s,
+                                first_token_s=s.first_token_s, done_s=now,
+                                n_tokens=len(s.out), admitted_s=s.admitted_s,
+                                token_s=s.token_s))
+                            slots[i] = None
+
+                # ------------------------------------------------ fidelity
+                if compiled is not None and steps % cfg.fidelity_every == 0:
+                    with trace.span("repro.sched.fidelity"):
+                        self._observe_fidelity(bucket, compiled, now, steps)
 
         return SchedulerReport(
             completions=completions, stats=stats,
@@ -523,6 +585,7 @@ class FixedBatchReference:
             # the engine blocks until the whole batch has arrived, then
             # until the previous batch drained
             now = max(now, max(r.arrival_s for r in batch))
+            admitted_s = now
             t = max(len(r.prompt) for r in batch)
             now += t * cost                       # padded bulk prefill
             steps += t
@@ -541,7 +604,9 @@ class FixedBatchReference:
                 stats.append(RequestStats(
                     rid=r.rid, arrival_s=r.arrival_s,
                     first_token_s=first_token_s, done_s=done,
-                    n_tokens=r.max_new_tokens))
+                    n_tokens=r.max_new_tokens, admitted_s=admitted_s,
+                    token_s=[first_token_s + k * cost
+                             for k in range(r.max_new_tokens)]))
                 total_tokens += r.max_new_tokens
         return SchedulerReport(
             completions=[], stats=stats, duration_s=now, steps=steps,

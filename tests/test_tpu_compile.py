@@ -3,7 +3,8 @@
 The TPU compiler is installed beside the CPU backend, and it compiles for a
 described v5e chip that is not attached.  These tests lower each kernel for
 one chip of a described ``v5e:2x2`` topology, in fp32 and bf16, and assert
-that the compiled program holds the kernel (``tpu_custom_call``).  They catch
+that the compiled program holds the kernel (``tpu_custom_call``) under its
+stable name, which is what a profile shows for it.  They catch
 what interpret mode cannot: block shapes the TPU lowering refuses,
 primitives it has no rule for, and layouts Mosaic cannot build.  Nothing
 runs, so they say nothing about results or times.
@@ -13,6 +14,7 @@ at a time may load the TPU library, and pytest-xdist workers each import
 this file.
 """
 import os
+import re
 
 import pytest
 
@@ -78,6 +80,10 @@ def _ssd_chunk(dt):
 
 KERNELS = {"split_matmul": _linear, "winograd": _winograd,
            "decode_attention": _decode_attention, "ssd_chunk": _ssd_chunk}
+#: each kernel's stable `name=`, which names its custom call in a profile
+CALL_NAMES = {"split_matmul": "split_matmul", "winograd": "winograd_conv",
+              "decode_attention": "decode_attention",
+              "ssd_chunk": "ssd_chunk"}
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
@@ -87,3 +93,7 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, dtype):
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    # (a batching transform prefixes the name: `vmap_decode_attention_`)
+    assert re.search(rf"%[\w.]*{CALL_NAMES[kernel]}[\w.]* = [^\n]*"
+                     r'custom_call_target="tpu_custom_call"',
+                     compiled.as_text())
