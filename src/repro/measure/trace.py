@@ -19,7 +19,8 @@ Span names of the program (`docs/ARCHITECTURE.md`, "Tracing"):
   * `repro.exec.run` ⊃ `repro.exec.segment` ⊃ `repro.exec.sync`
     (`runtime/executor.py`);
   * `repro.sched.step` ⊃ `repro.sched.inputs` / `.decode` / `.sample` /
-    `.emit` / `.fidelity` (`serving/scheduler.py`).
+    `.emit` / `.fidelity` (`serving/scheduler.py`), and `.sample` ⊃
+    `repro.sched.read` (`serving/engine.py` `sample_tokens`).
 
 jax is resolved on the first span, not at import: this package stays
 import-light (`python -m repro lint`).

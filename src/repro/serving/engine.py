@@ -31,9 +31,12 @@ on, consumed automatically by `repro.serving.ContinuousScheduler`).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import TYPE_CHECKING, Any, List, Optional, Tuple
 
 import numpy as np
+
+from repro.measure import trace
 
 if TYPE_CHECKING:
     import jax
@@ -58,26 +61,55 @@ class Completion:
     tokens: List[int]
 
 
-def sample_tokens(rng, logits: jax.Array, temperatures
-                  ) -> Tuple[jax.Array, Any]:
-    """Per-request sampling shared by the fixed-batch engine and the
-    continuous scheduler: row i of `logits` samples at `temperatures[i]`
-    (<= 0 = greedy).  Returns (tokens, rng) — the key is split (and thus
-    consumed) only when some row actually samples, so all-greedy batches
-    are rng-invariant."""
+def _greedy_tokens(logits):
+    import jax.numpy as jnp
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
+def _sampled_tokens(rng, logits, temps):
     import jax
     import jax.numpy as jnp
-    temps = jnp.asarray(temperatures, jnp.float32)
-    if temps.ndim == 0:
-        temps = jnp.full((logits.shape[0],), temps)
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    if not bool(jnp.any(temps > 0.0)):
-        return greedy, rng
+    greedy = _greedy_tokens(logits)
     rng, sub = jax.random.split(rng)
     safe = jnp.where(temps > 0.0, temps, 1.0)
     sampled = jax.random.categorical(
         sub, logits / safe[:, None], axis=-1).astype(jnp.int32)
     return jnp.where(temps > 0.0, sampled, greedy), rng
+
+
+@functools.cache
+def _programs():
+    """The two sampling programs, (greedy, sampled), jitted once per
+    process (jax is imported on first use: this module is import-light)."""
+    import jax
+    return jax.jit(_greedy_tokens), jax.jit(_sampled_tokens)
+
+
+def sample_tokens(rng, logits: jax.Array, temperatures
+                  ) -> Tuple[np.ndarray, Any]:
+    """Per-request sampling shared by the fixed-batch engine and the
+    continuous scheduler: row i of `logits` samples at `temperatures[i]`
+    (<= 0 = greedy; a scalar applies to every row).
+
+    Greedy or sampled is decided on the host from the temperatures, so
+    the choice waits for nothing on the device; each path is one jitted
+    program.  The key is split (and thus consumed) only when some row
+    samples, so all-greedy batches are rng-invariant.  The tokens are
+    read back to the host once, inside a `repro.sched.read` span: that
+    read is where the host waits for the decode step that made `logits`.
+    Returns (tokens, rng): an int32 `np.ndarray` of shape (B,) and the
+    key, which stays on the device."""
+    import jax
+    temps = np.asarray(temperatures, np.float32)
+    if temps.ndim == 0:
+        temps = np.full((logits.shape[0],), temps, np.float32)
+    greedy, sampled = _programs()
+    if (temps > 0.0).any():
+        tokens, rng = sampled(rng, logits, temps)
+    else:
+        tokens = greedy(logits)
+    with trace.span("repro.sched.read"):
+        return jax.device_get(tokens), rng
 
 
 class ServingEngine:
@@ -181,10 +213,11 @@ class ServingEngine:
             return None
         return self._fidelity_log[-1] - self._fidelity_log[0]
 
-    def _sample(self, logits: jax.Array, temperatures) -> jax.Array:
+    def _sample(self, logits: jax.Array, temperatures) -> np.ndarray:
         """Per-request sampling: row i of `logits` samples at
         `temperatures[i]` (<= 0 = greedy), so mixed greedy/temperature
-        batches are correct.  All-greedy batches never consume rng."""
+        batches are correct.  All-greedy batches never consume rng.
+        The tokens come back on the host."""
         tok, self.rng = sample_tokens(self.rng, logits, temperatures)
         return tok
 

@@ -39,9 +39,12 @@ Spans (`repro.measure.trace`, recorded under a profiler session): each
 step is a `repro.sched.step`; inside it `repro.sched.inputs` (building
 the step's tokens/positions/temperatures and handing them to the
 device), `repro.sched.decode` (the jitted decode call),
-`repro.sched.sample` (`sample_tokens`), `repro.sched.emit` (per-row token
-reads and slot bookkeeping) and, with a portfolio, `repro.sched.fidelity`.
-Admission and bucket selection are the step's self time.
+`repro.sched.sample` (`sample_tokens`: the sampling program's dispatch,
+then `repro.sched.read`, the step's one read of its tokens to the host,
+which waits for the decode program), `repro.sched.emit` (slot
+bookkeeping over the host tokens; it reads nothing from the device) and,
+with a portfolio, `repro.sched.fidelity`.  Admission and bucket
+selection are the step's self time.
 """
 from __future__ import annotations
 
@@ -112,10 +115,9 @@ class RequestStats:
     """One finished request on the scheduler clock.
 
     `admitted_s` is when it took a slot.  `token_s[k]` stamps its k-th
-    output token: the scheduler clock after the step's sampling is
-    dispatched, before the token is read back to the host (a change that
-    moves the read must move the stamp with it).  `first_token_s` equals
-    `token_s[0]`.
+    output token: the scheduler clock after `sample_tokens` has returned
+    the step's tokens on the host (a change that moves that read must
+    move the stamp with it).  `first_token_s` equals `token_s[0]`.
     """
 
     rid: int
@@ -495,7 +497,8 @@ class ContinuousScheduler:
                 # sampling temperature applies only to rows past their
                 # prompt; rows mid-prefill (and free rows) stay greedy so
                 # they never consume rng — admission order cannot shift
-                # another request's sampled tokens
+                # another request's sampled tokens.  `sampled` is on the
+                # host: the step's one device read is inside this span
                 with trace.span("repro.sched.sample"):
                     sampled, self.rng = sample_tokens(self.rng, logits, temps)
                 steps += 1

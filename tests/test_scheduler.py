@@ -2,8 +2,11 @@
 drift-triggered replan loop (PR 8).
 
 Covers: scheduler-vs-solo token equality (continuous batching must not
-change greedy completions), mixed-length left-padded batches through the
-fixed-batch engine, the decode early-break accounting, windowed drift +
+change greedy completions), `sample_tokens` against the eager sampling
+formula and its one program per path, the scheduler's contract with the
+chip benchmark's watch of `sample_tokens` (one call per step, a host
+array, one index per emitting row), mixed-length left-padded batches
+through the fixed-batch engine, the decode early-break accounting, windowed drift +
 the latest-vs-first alias, portfolio select/save/load/tamper, bucketed
 plan provenance byte-compat, Poisson traffic determinism, calibrator
 composition, and the two serving acceptance criteria: the portfolio
@@ -123,6 +126,129 @@ def test_scheduler_validates_request_length(gqa_model):
         sched.run(_reqs(prompts=[14], max_new=8))
     with pytest.raises(ValueError, match="unknown clock"):
         SchedulerConfig(clock="sundial")
+
+
+# ------------------------------------------------------------- sampling
+
+def _eager_sample(rng, logits, temperatures):
+    """The step's sampling as it was before it ran as one program: eager
+    ops, the greedy-or-sampled choice read back from the device."""
+    import jax.numpy as jnp
+    temps = jnp.asarray(temperatures, jnp.float32)
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    if not bool(jnp.any(temps > 0.0)):
+        return greedy, rng
+    rng, sub = jax.random.split(rng)
+    safe = jnp.where(temps > 0.0, temps, 1.0)
+    sampled = jax.random.categorical(
+        sub, logits / safe[:, None], axis=-1).astype(jnp.int32)
+    return jnp.where(temps > 0.0, sampled, greedy), rng
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("temps", [
+    (0.0,) * 8, (0.0, 0.7, 0.0, 1.3, 0.0, 0.0, 0.7, 0.2), (0.7,) * 8])
+def test_sample_tokens_matches_the_eager_formula(dtype, temps):
+    """One program per path gives the eager formula's tokens, token for
+    token, and the same next key; an all-greedy step leaves the key as
+    it was.  The tokens come back as a host int32 vector."""
+    from repro.serving import engine
+    logits = jax.numpy.asarray(
+        3.0 * np.random.default_rng(5).normal(size=(8, 512)), dtype)
+    temps = np.asarray(temps, np.float32)
+    rng = jax.random.PRNGKey(11)
+    for _ in range(3):
+        got, got_rng = engine.sample_tokens(rng, logits, temps)
+        want, want_rng = _eager_sample(rng, logits, temps)
+        assert isinstance(got, np.ndarray)
+        assert got.dtype == np.int32 and got.shape == (8,)
+        np.testing.assert_array_equal(got, np.asarray(want))
+        np.testing.assert_array_equal(np.asarray(got_rng),
+                                      np.asarray(want_rng))
+        if not (temps > 0).any():
+            assert got_rng is rng
+        rng = got_rng
+
+
+def test_sample_tokens_scalar_temperature_and_one_program_per_path():
+    """A scalar temperature applies to every row; over 20 steps at one
+    shape each path compiles once."""
+    from repro.serving import engine
+    greedy, sampled = engine._programs()
+    greedy.clear_cache()
+    sampled.clear_cache()
+    logits = jax.numpy.asarray(
+        np.random.default_rng(6).normal(size=(4, 64)), jax.numpy.float32)
+    rng = jax.random.PRNGKey(3)
+    for _ in range(20):
+        tok, same = engine.sample_tokens(rng, logits, 0.0)
+        assert same is rng
+        np.testing.assert_array_equal(tok, np.argmax(np.asarray(logits), -1))
+        got, rng2 = engine.sample_tokens(rng, logits, 0.9)
+        want, want_rng = _eager_sample(rng, logits, np.full(4, 0.9))
+        np.testing.assert_array_equal(got, np.asarray(want))
+        np.testing.assert_array_equal(np.asarray(rng2), np.asarray(want_rng))
+        rng = rng2
+    assert greedy._cache_size() == 1
+    assert sampled._cache_size() == 1
+
+
+def _watched_run(monkeypatch, sched, reqs):
+    """Run `sched` with `sample_tokens` wrapped as the chip benchmark's
+    chat runner wraps it: each call's result is handed back behind an
+    object that notes every row the scheduler indexes."""
+    from repro.serving import engine
+    real = engine.sample_tokens
+    calls = []
+
+    class Read:
+        def __init__(self, tokens):
+            self.tokens, self.rows = tokens, []
+
+        def __getitem__(self, i):
+            self.rows.append(i)
+            return self.tokens[i]
+
+    def watched(rng, logits, temps):
+        tokens, rng = real(rng, logits, temps)
+        read = Read(tokens)
+        calls.append(read)
+        return read, rng
+
+    monkeypatch.setattr(engine, "sample_tokens", watched)
+    rep = sched.run(reqs)
+    monkeypatch.setattr(engine, "sample_tokens", real)
+    return rep, calls
+
+
+def test_scheduler_keeps_the_benchmark_watch_contract(gqa_model,
+                                                      monkeypatch):
+    """The scheduler calls `engine.sample_tokens` through the module once
+    per step, gets a host array back, and indexes each emitting row once:
+    the rows read are the tokens served.  Same seed, same completions."""
+    cfg, model, params = gqa_model
+    reqs = _reqs(prompts=[3, 6, 2, 5, 4, 7], max_new=[5, 3, 6, 4, 2, 5],
+                 arrivals=[0.0, 0.0, 0.001, 0.002, 0.004, 0.006],
+                 temps=[0.0, 0.7, 0.7, 0.0, 0.7, 0.0])
+    runs = []
+    for _ in range(2):
+        sched = ContinuousScheduler(
+            cfg, model, params,
+            config=SchedulerConfig(max_batch=3, max_len=32, seed=9))
+        rep, calls = _watched_run(monkeypatch, sched, reqs)
+        assert len(calls) == rep.steps
+        for c in calls:
+            assert isinstance(c.tokens, np.ndarray)
+            assert c.tokens.dtype == np.int32
+            assert c.tokens.shape == (3,)
+            assert len(set(c.rows)) == len(c.rows)
+        assert sum(len(c.rows) for c in calls) == rep.total_tokens
+        served = sorted(t for comp in rep.completions for t in comp.tokens)
+        read = sorted(int(c.tokens[i]) for c in calls for i in c.rows)
+        assert served == read
+        runs.append({c.rid: c.tokens for c in rep.completions})
+    assert sorted(runs[0]) == [r.rid for r in reqs]
+    assert runs[0] == runs[1]
 
 
 # --------------------------------------------- fixed-batch engine repairs

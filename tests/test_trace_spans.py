@@ -31,8 +31,11 @@ UNITS = [("conv", ConvOp(8, 8, 8, 16, 3, 1)),
          ("pool", 4 * 4 * 4 * 16),
          ("conv", ConvOp(4, 4, 16, 24, 3, 1)),
          ("linear", LinearOp(1, 4 * 4 * 24, 32))]
+#: the spans inside a scheduler step, one of each per step;
+#: `repro.sched.read` sits inside `repro.sched.sample`
 STEP_CHILDREN = {"repro.sched.inputs", "repro.sched.decode",
-                 "repro.sched.sample", "repro.sched.emit"}
+                 "repro.sched.sample", "repro.sched.read",
+                 "repro.sched.emit"}
 
 
 def _executor() -> PlanExecutor:
@@ -226,6 +229,9 @@ def test_scheduler_records_one_step_span_per_step(gqa_model, tmp_path):
         assert len(_named(children, name)) == rep.steps
     for c in children:
         assert sum(_inside(c, s) for s in steps) == 1
+    samples = _named(children, "repro.sched.sample")
+    for r in _named(children, "repro.sched.read"):
+        assert sum(_inside(r, s) for s in samples) == 1
 
 
 def test_token_stamps_and_queue_wait_on_the_virtual_clock(gqa_model):
