@@ -27,10 +27,12 @@ HERE = Path(__file__).resolve().parents[1]
 ROOT = HERE.parents[1]
 sys.path.insert(0, str(HERE))
 sys.path.insert(0, str(ROOT / "src"))
+sys.path.insert(0, str(Path(__file__).resolve().parent))
 
 import counts      # noqa: E402
 import harness     # noqa: E402
 import reference   # noqa: E402
+import spec_checks  # noqa: E402
 import traffic     # noqa: E402
 import tracing     # noqa: E402
 
@@ -227,6 +229,7 @@ def test_chat_reference_matches_served_tokens(cpu_peak):
     ("resnet18.b1", {}, {}),
     ("codeqwen15_7b.blocks.b1", TINY_DECODER, {"cache_len": 64}),
     ("codeqwen15_7b.chat", TINY_DECODER, TINY_CHAT),
+    ("vgg16.b1", {}, {}),
 ])
 def test_control_fails_the_limit(workload, config, mix):
     """The reference a step below the stated precision, put in the
@@ -254,18 +257,10 @@ def test_lower_precision_output_fails(cpu_peak):
 # ------------------------------------------------- discovery, generators
 
 def test_every_cell_resolves():
-    names = [w["name"] for w in SPEC["workloads"]]
-    assert names == ["resnet18.b1", "codeqwen15_7b.chat",
-                     "codeqwen15_7b.blocks.b1", "resnet18.b1.split"]
-    for name in names:
-        cell = harness.resolve(SPEC, name)
-        assert cell["runner"].is_file()
-        assert cell["limits_file"].is_file()
-        e2e = {m["name"] for m in cell["metrics"]["end_to_end"]}
-        assert "setup_s" in e2e and len(e2e) >= 2
-        assert cell["metrics"]["per_layer"]
-        for m in cell["metrics"]["per_layer"]:
-            assert m["moves"] in e2e
+    """Every declared cell resolves to its files, reports `setup_s` and
+    another end-to-end metric, and carries the per-layer metrics that its
+    end-to-end metrics call for; every accepted cell is still there."""
+    spec_checks.check_spec(SPEC, HERE)
 
 
 def test_a_cell_added_as_files_is_found(tmp_path):
@@ -292,6 +287,63 @@ def test_a_cell_added_as_files_is_found(tmp_path):
         {"dummy_count.infer": {"value": 1.0, "unit": "count"}}
     with pytest.raises(harness.CellError):
         harness.resolve(spec, "resnet18.nowhere", root)
+
+
+def test_a_serve_cell_added_as_files_passes_the_checks(tmp_path):
+    """What adding a decoder served on the chat path takes: a
+    configuration, a traffic mix, a limits file and a metric reader as new
+    files, and entries appended to the spec.  No file that is there
+    changes, and the spec's checks pass; they fail where the new cell is
+    left out of a metric that its end-to-end metrics call for."""
+    root = tmp_path / "benchmarks" / "chip"
+    shutil.copytree(HERE, root, ignore=shutil.ignore_patterns(
+        "__pycache__", "tests"))
+    before = {p: p.read_bytes() for p in root.rglob("*") if p.is_file()}
+    new = {
+        "configs/tiny_decoder.json": dict(harness.load_json(
+            HERE / "configs" / "codeqwen15_7b.json"),
+            name="tiny_decoder", **TINY_DECODER),
+        "traffic/chat_slow.json": dict(harness.load_json(
+            HERE / "traffic" / "chat.json"), rate_per_s=4.0),
+        "limits/tiny_decoder.chat_slow.json": {"logit_gap": {"limit": 0.1}},
+    }
+    for rel, doc in new.items():
+        (root / rel).write_text(json.dumps(doc))
+    (root / "metrics" / "route_ms.moe.py").write_text(
+        "def read(ctx):\n    return None\n")
+
+    spec = json.loads(json.dumps(SPEC))
+    spec["configs"].append({
+        "name": "tiny_decoder", "source": "https://example.org/tiny",
+        "file": "benchmarks/chip/configs/tiny_decoder.json",
+        "reduced": [], "why": "x"})
+    cell = "tiny_decoder.chat_slow"
+    spec["workloads"].append({"name": cell, "config": "tiny_decoder",
+                              "traffic": "chat_slow", "chips": 1,
+                              "why": "x"})
+    # the new cell reports what a served cell reports
+    itl = {m["name"]: m for m in spec["end_to_end"]}["itl_p95_ms"]
+    served = itl["workloads"][0]
+    for m in spec["end_to_end"] + spec["per_layer"]:
+        if served in m.get("workloads", ()):
+            m["workloads"].append(cell)
+    spec["per_layer"].append({
+        "name": "route_ms.moe", "unit": "ms", "better": "lower",
+        "source": "program_span", "layer": "model step",
+        "moves": "itl_p95_ms", "workloads": [cell]})
+
+    spec_checks.check_spec(spec, root)
+    after = {p: p.read_bytes() for p in root.rglob("*") if p.is_file()}
+    assert {p: after[p] for p in before} == before
+    assert sorted(str(p.relative_to(root)) for p in set(after) - set(before)) \
+        == sorted([*new, "metrics/route_ms.moe.py"])
+    got = harness.resolve(spec, cell, root)
+    assert "route_ms.moe" in {m["name"] for m in got["metrics"]["per_layer"]}
+
+    read_ms = {m["name"]: m for m in spec["per_layer"]}["read_ms.serve"]
+    read_ms["workloads"].remove(cell)
+    with pytest.raises(AssertionError, match="read_ms.serve"):
+        spec_checks.check_spec(spec, root)
 
 
 def test_open_loop_generator():
@@ -337,6 +389,7 @@ def test_token_times_by_hand():
 @pytest.mark.parametrize("workload,config,mix", [
     ("resnet18.b1", {}, {}),
     ("codeqwen15_7b.blocks.b1", TINY_DECODER, {"cache_len": 64}),
+    ("vgg16.b1", {}, {}),
 ])
 def test_altered_answer_is_not_correct(cpu_peak, monkeypatch, workload,
                                        config, mix):

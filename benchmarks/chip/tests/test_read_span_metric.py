@@ -19,8 +19,10 @@ HERE = Path(__file__).resolve().parents[1]
 ROOT = HERE.parents[1]
 sys.path.insert(0, str(HERE))
 sys.path.insert(0, str(ROOT / "src"))
+sys.path.insert(0, str(Path(__file__).resolve().parent))
 
 import harness     # noqa: E402
+import spec_checks  # noqa: E402
 import tracing     # noqa: E402
 
 SPEC = harness.load_json(ROOT / "BENCHMARK.json")
@@ -69,15 +71,12 @@ def test_read_ms_reads_nothing_without_read_spans():
 
 
 def test_read_ms_is_declared_for_the_chat_cell():
-    m = {m["name"]: m for m in SPEC["per_layer"]}[NAME]
-    assert (m["unit"], m["better"], m["source"], m["layer"], m["moves"]) \
-        == ("ms", "lower", "program_span", "scheduler", "itl_p95_ms")
-    assert m["workloads"] == ["codeqwen15_7b.chat"]
-    assert SPEC["per_layer"][-1] is m
-    for w in SPEC["workloads"]:
-        cell = harness.resolve(SPEC, w["name"])
-        got = {x["name"] for x in cell["metrics"]["per_layer"]}
-        assert (NAME in got) == (w["traffic"] == "chat")
+    """Declared as it was (unit, direction, source, layer, what it moves),
+    and carried by exactly the cells that report `itl_p95_ms`."""
+    spec_checks.check_spec(SPEC, HERE)
+    assert spec_checks.DECLARED[NAME] == \
+        ("ms", "lower", "program_span", "scheduler", "itl_p95_ms")
+    assert NAME in spec_checks.CARRIES["itl_p95_ms"]
 
 
 @pytest.fixture

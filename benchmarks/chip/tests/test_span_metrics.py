@@ -20,9 +20,11 @@ HERE = Path(__file__).resolve().parents[1]
 ROOT = HERE.parents[1]
 sys.path.insert(0, str(HERE))
 sys.path.insert(0, str(ROOT / "src"))
+sys.path.insert(0, str(Path(__file__).resolve().parent))
 
 import harness     # noqa: E402
 import spans       # noqa: E402
+import spec_checks  # noqa: E402
 import tracing     # noqa: E402
 
 SPEC = harness.load_json(ROOT / "BENCHMARK.json")
@@ -146,14 +148,10 @@ def test_every_new_metric_is_declared_for_its_cells():
         m = declared[name]
         assert m["unit"] == "ms" and m["better"] == "lower"
         assert (HERE / "metrics" / f"{name}.py").is_file()
-    for w in SPEC["workloads"]:
-        cell = harness.resolve(SPEC, w["name"])
-        got = {m["name"] for m in cell["metrics"]["per_layer"]}
-        if w["traffic"] == "chat":
-            assert set(SERVE_METRICS) <= got
-        else:
-            unit = "decode" if "blocks" in w["name"] else "infer"
-            assert {f"{m}.{unit}" for m in EXEC_METRICS} <= got
+    # each cell carries them by the end-to-end metrics it reports
+    carried = {n for names in spec_checks.CARRIES.values() for n in names}
+    assert set(NEW) <= carried
+    spec_checks.check_spec(SPEC, HERE)
 
 
 # ---------------------------------------------- recorded on the CPU
