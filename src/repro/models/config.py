@@ -11,6 +11,19 @@ from typing import Optional
 
 
 @dataclasses.dataclass(frozen=True)
+class RopeScaling:
+    """YaRN rope scaling, as a published config.json's `rope_scaling`
+    states it for `type: "yarn"` (DeepSeek-V2).  No other scaling is
+    modelled."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
     family: str                      # dense | moe | ssm | hybrid | vlm | audio
@@ -27,6 +40,7 @@ class ModelConfig:
     qkv_bias: bool = False
     qk_norm: bool = False
     rope_theta: float = 1e4
+    rope_scaling: Optional[RopeScaling] = None   # None = plain rope
     sliding_window: int = 0          # 0 = full attention
     local_global_ratio: int = 0      # gemma3: N local layers per 1 global
 
@@ -41,6 +55,7 @@ class ModelConfig:
     n_shared_experts: int = 0
     experts_per_token: int = 0
     moe_d_ff: int = 0                # per-expert hidden dim
+    norm_topk_prob: bool = True      # renormalise the top-k gates to sum 1
     first_dense_layers: int = 0      # leading dense layers (deepseek)
     moe_interleave: int = 1          # 1 = every layer MoE; 2 = alternate
     # dispatch tokens within each data shard (shard_map partial-manual):

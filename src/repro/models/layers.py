@@ -10,6 +10,7 @@ Conventions:
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Dict, Tuple
 
 import jax
@@ -30,19 +31,67 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array:
 
 
 # ----------------------------------------------------------------- rope
-def rope_frequencies(head_dim: int, theta: float) -> jax.Array:
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's magnitude correction for a context stretched `factor` times
+    (DeepSeek-V2's `yarn_get_mscale`)."""
+    if factor <= 1:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_softmax_factor(scaling) -> float:
+    """What YaRN multiplies the attention softmax scale by: mscale(factor,
+    mscale_all_dim) squared, or 1 without scaling."""
+    if scaling is None or not scaling.mscale_all_dim:
+        return 1.0
+    return yarn_mscale(scaling.factor, scaling.mscale_all_dim) ** 2
+
+
+def _yarn_inv_freq(head_dim: int, theta: float, scaling) -> np.ndarray:
+    """DeepSeek-V2's YaRN inverse frequencies (float64): pairs below the
+    correction range keep the base frequency, pairs above it are divided
+    by `factor`, and a linear ramp blends the pairs between.  The range is
+    found in units of `head_dim` and the ramp runs over its pairs, as the
+    published modelling code has it."""
+    def correction_dim(rotations: float) -> float:
+        return (head_dim * math.log(scaling.original_max_position_embeddings
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(scaling.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(scaling.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    base = 1.0 / theta ** (np.arange(0, head_dim, 2, dtype=np.float64)
+                           / head_dim)
+    ramp = np.clip((np.arange(head_dim // 2) - low) / (high - low), 0, 1)
+    return base / scaling.factor * ramp + base * (1 - ramp)
+
+
+def rope_frequencies(head_dim: int, theta: float,
+                     scaling=None) -> jax.Array:
+    if scaling is not None:
+        return jnp.asarray(_yarn_inv_freq(head_dim, theta, scaling),
+                           jnp.float32)
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2,
                                        dtype=jnp.float32) / head_dim))
 
 
 def apply_rope(x: jax.Array, positions: jax.Array,
-               theta: float = 1e4) -> jax.Array:
-    """x: (..., T, H, hd); positions: (..., T)."""
+               theta: float = 1e4, scaling=None) -> jax.Array:
+    """x: (..., T, H, hd); positions: (..., T).  Rotates halves (the
+    dimension i pairs with i + hd/2).  `scaling` (a `RopeScaling`) gives
+    YaRN's frequencies and scales cos and sin by mscale / mscale_all_dim."""
     hd = x.shape[-1]
-    freqs = rope_frequencies(hd, theta)                      # (hd/2,)
+    freqs = rope_frequencies(hd, theta, scaling)             # (hd/2,)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., T, hd/2)
     cos = jnp.cos(angles)[..., None, :]                      # (..., T, 1, hd/2)
     sin = jnp.sin(angles)[..., None, :]
+    if scaling is not None:
+        attn = (yarn_mscale(scaling.factor, scaling.mscale)
+                / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        if attn != 1.0:
+            cos, sin = cos * attn, sin * attn
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
     return out.astype(x.dtype)

@@ -1,19 +1,28 @@
-"""Mixture-of-Experts layer with capacity-based einsum dispatch.
+"""Mixture-of-Experts layer: dropless grouped-matmul routing, and a
+capacity-based scatter dispatch for shard-local sharded runs.
 
-Expert-parallel execution is the MoE analogue of the paper's co-execution:
-output "channels" (here: experts) are partitioned across compute groups.
-The dispatch uses the GShard/Switch dense-einsum formulation — one-hot
-dispatch/combine tensors with a fixed per-expert capacity — because it
-(1) lowers to all-to-all-style collectives under pjit when the expert axis
-is sharded, and (2) keeps compiled FLOPs proportional to top-k (not to the
-total expert count).
+The layer is dropless: the (row, choice) pairs are sorted by expert and
+each expert's rows are multiplied by its weights in one grouped matmul
+(`grouped_matmul`: the Pallas `megablox.gmm` kernel when lowered for a
+TPU, which skips experts no row chose; `jax.lax.ragged_dot` elsewhere and
+under an activation mesh, where the partitioner places it).  No token is
+ever dropped, whatever the routing.
 
+With cfg.moe_local_dispatch under a mesh, expert-parallel execution is
+the MoE analogue of the paper's co-execution: output "channels" (here:
+experts) are partitioned across compute groups, and the dispatch uses a
+per-expert capacity buffer written by scatter and read back by gather
+(GShard/Switch), kept local to each data shard; pairs beyond an expert's
+capacity are dropped there.
+
+Gates are the router's softmax over all experts, top-k; they are
+renormalised to sum 1 only when the config's `norm_topk_prob` says so.
 Aux losses: switch load-balance loss + router z-loss (returned to the
 training loop).
 """
 from __future__ import annotations
 
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -21,9 +30,12 @@ import numpy as np
 
 from repro.models.config import ModelConfig
 from repro.models.layers import init_mlp, mlp
-from repro.sharding.ctx import constrain
+from repro.sharding.ctx import batch_axes, constrain, current_mesh
 
 Params = Dict[str, jax.Array]
+
+#: the routed experts' weights, stacked (E, ...) in a layer's params
+EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
 
 
 def init_moe(rng, cfg: ModelConfig, dtype=jnp.bfloat16) -> Params:
@@ -42,68 +54,218 @@ def init_moe(rng, cfg: ModelConfig, dtype=jnp.bfloat16) -> Params:
     return p
 
 
+def route(p: Params, xt: jax.Array, cfg: ModelConfig):
+    """Router in float32: (logits (N,E), probs (N,E), gates (N,k),
+    experts (N,k))."""
+    logits = jnp.matmul(xt.astype(jnp.float32), p["router"],
+                        precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    gates, experts = jax.lax.top_k(probs, cfg.experts_per_token)
+    if cfg.norm_topk_prob:
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    return logits, probs, gates, experts
+
+
+def _aux_loss(logits, probs, experts, e: int) -> jax.Array:
+    """Switch load-balance loss + router z-loss."""
+    me = probs.mean(0)                                        # (E,)
+    ce = jax.nn.one_hot(experts, e, dtype=jnp.float32).sum(1).mean(0)
+    return e * jnp.sum(me * ce) + 1e-3 * jnp.mean(
+        jnp.square(jax.nn.logsumexp(logits, axis=-1)))
+
+
+def _groups(experts, valid, e: int):
+    """The expert of each (row, choice) pair, flat (N*k,), with `e` (no
+    expert) for the pairs of rows that are not `valid`; and how many
+    pairs each expert receives (E,)."""
+    flat = experts.reshape(-1)                       # pair j is row j // k
+    if valid is not None:
+        flat = jnp.where(jnp.repeat(valid, experts.shape[-1]), flat, e)
+    sizes = jnp.zeros((e,), jnp.int32).at[flat].add(1, mode="drop")
+    return flat, sizes
+
+
+def _counts(sizes: jax.Array) -> jax.Array:
+    """int32 (2,): experts that received a pair, and the pairs routed."""
+    return jnp.stack([(sizes > 0).sum(), sizes.sum()]).astype(jnp.int32)
+
+
 def moe_layer(p: Params, x: jax.Array, cfg: ModelConfig,
-              capacity_factor: float = 1.25) -> Tuple[jax.Array, jax.Array]:
-    """x: (B, T, D) -> (y, aux_loss).
+              capacity_factor: float = 1.25, valid=None, layer=None
+              ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """x: (B, T, D) -> (y, aux_loss, counts).
 
-    Dispatch is scatter/gather-based: tokens are written into a per-expert
-    capacity buffer via scatter-add and read back via gather.  The earlier
-    GShard-style dense (N, E, C) one-hot einsum dispatch made the
-    llama4-scout prefill_32k dry-run collective-bound with a 2% useful-FLOP
-    ratio (N=1M tokens -> the dispatch/combine tensors dwarf the expert
-    math); the scatter form moves O(N*k*D) bytes instead of O(N*E*C)
-    (EXPERIMENTS.md §Perf iteration B).
+    With `layer`, the routed experts' weights (`EXPERT_WEIGHTS`) are
+    stacks over layers, of which this layer's are `[layer]`.
 
-    With cfg.moe_local_dispatch the whole dispatch+expert+combine runs
-    under a partial-manual shard_map over the batch axes so the scatter
-    stays shard-local with a per-shard capacity slice (§Perf B2); expert
-    weights remain on the auto model axis.
+    `valid` (B,) marks the rows that carry a request (None: all); the
+    others are routed to no expert and give zeros.  `counts` (int32, 2)
+    holds the experts that the valid rows chose and the (row, choice)
+    pairs they routed.
+
+    With cfg.moe_local_dispatch under a mesh, the whole dispatch+expert+
+    combine runs under a partial-manual shard_map over the batch axes, so
+    the scatter into the capacity buffer stays shard-local with a
+    per-shard capacity slice (EXPERIMENTS.md §Perf B2); expert weights
+    remain on the auto model axis.  The scatter form moves O(N*k*D) bytes,
+    where the earlier GShard-style dense (N, E, C) one-hot einsum made the
+    llama4-scout prefill_32k dry-run collective-bound (§Perf B).
     """
     b, t, d = x.shape
-    e, k = cfg.n_experts, cfg.experts_per_token
     n = b * t
     xt = x.reshape(n, d)
+    valid_t = None if valid is None else jnp.repeat(valid, t)
+    mesh = current_mesh()
+    if mesh is not None and cfg.moe_local_dispatch:
+        if layer is not None:
+            p = dict(p, **{w: p[w][layer] for w in EXPERT_WEIGHTS})
+        y, aux, counts = _moe_local(p, xt, cfg, mesh, capacity_factor,
+                                    valid_t)
+    else:
+        y, aux, counts = _moe_dropless(
+            p, xt, cfg, valid_t, impl=None if mesh is None else "ragged_dot",
+            layer=layer)
+    return y.reshape(b, t, d), aux, counts
 
-    if cfg.moe_local_dispatch:
-        from repro.sharding.ctx import batch_axes, current_mesh
-        from jax.sharding import PartitionSpec as P
-        mesh = current_mesh()
-        axes = tuple(a for a in batch_axes()
-                     if mesh is not None and a in mesh.shape)
-        shards = 1
-        for a in axes:
-            shards *= mesh.shape[a]
-        if mesh is not None and shards > 1 and n % shards == 0 \
-                and (n // shards) * k >= e:
-            cap_local = max(1, int(capacity_factor * (n // shards) * k / e))
 
-            def local(xt_l):
-                y_l, aux_l = _moe_core(p, xt_l, cfg, cap_local)
-                return y_l, aux_l[None]
+def _moe_local(p: Params, xt: jax.Array, cfg: ModelConfig, mesh,
+               capacity_factor: float, valid
+               ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """The capacity dispatch, shard-local over the batch axes where the
+    rows divide among the shards, else over all rows."""
+    n = xt.shape[0]
+    e, k = cfg.n_experts, cfg.experts_per_token
+    valid = jnp.ones((n,), bool) if valid is None else valid
+    from jax.sharding import PartitionSpec as P
+    axes = tuple(a for a in batch_axes() if a in mesh.shape)
+    shards = 1
+    for a in axes:
+        shards *= mesh.shape[a]
+    if shards > 1 and n % shards == 0 and (n // shards) * k >= e:
+        cap_local = max(1, int(capacity_factor * (n // shards) * k / e))
 
-            y, aux = jax.shard_map(
-                local, mesh=mesh,
-                in_specs=P(axes),
-                out_specs=(P(axes), P(axes)),
-                axis_names=set(axes), check_vma=False)(xt)
-            return y.reshape(b, t, d), aux.mean()
+        def local(xt_l, valid_l):
+            y_l, aux_l, experts_l = _moe_core(p, xt_l, cfg, cap_local)
+            return y_l, aux_l[None], _groups(experts_l, valid_l, e)[1][None]
+
+        y, aux, sizes = jax.shard_map(
+            local, mesh=mesh,
+            in_specs=(P(axes), P(axes)),
+            out_specs=(P(axes), P(axes), P(axes)),
+            axis_names=set(axes), check_vma=False)(xt, valid)
+        return y, aux.mean(), _counts(sizes.sum(0))
 
     capacity = max(1, int(capacity_factor * n * k / e))
-    y, aux = _moe_core(p, xt, cfg, capacity)
-    return y.reshape(b, t, d), aux
+    y, aux, experts = _moe_core(p, xt, cfg, capacity)
+    return y, aux, _counts(_groups(experts, valid, e)[1])
+
+
+# ------------------------------------------------------- grouped matmul
+#: elements of one weight tile of the grouped matmul (bf16: 3 MiB, twice
+#: over for double buffering), an untuned budget
+_GMM_TILE_ELEMS = 1536 * 1024
+
+
+def _tile_choices(dim: int):
+    """Tile sizes the TPU accepts for a dimension: the whole dimension, or
+    a multiple of 128 that divides it."""
+    return sorted({dim} | {t for t in range(128, dim, 128) if dim % t == 0})
+
+
+def gmm_tiling(m: int, k: int, n: int) -> Tuple[int, int, int]:
+    """(tm, tk, tn) for `megablox.gmm`: 128 rows (fewer when m is small),
+    and the largest weight tile within `_GMM_TILE_ELEMS`."""
+    tm = 128 if m >= 128 else -(-m // 16) * 16
+    best = (_tile_choices(k)[0], _tile_choices(n)[0])
+    for tk in _tile_choices(k):
+        for tn in _tile_choices(n):
+            if tk * tn <= _GMM_TILE_ELEMS and \
+                    (tk * tn, tk) > (best[0] * best[1], best[0]):
+                best = (tk, tn)
+    return tm, best[0], best[1]
+
+
+def grouped_matmul(x: jax.Array, w: jax.Array, sizes: jax.Array,
+                   impl: Optional[str] = None, layer=None) -> jax.Array:
+    """Rows of `x` (M, K), sorted by group, times their group's `w`
+    (G, K, N): group g owns the `sizes[g]` rows after those of groups
+    before it.  Rows past `sizes.sum()` give zeros.
+
+    With `layer`, `w` is a stack (L, G, K, N) and the groups are those of
+    `w[layer]`.  The kernel then reads the stack in place, picking the
+    layer's groups by their offset in it: a Pallas call reads its operands
+    whole, so a slice handed to it would first be copied out, every
+    group's weights and not only the chosen ones.
+
+    `impl`: "gmm" (the Pallas kernel), "gmm_interpret" (the same kernel
+    in Pallas' interpreter), "ragged_dot" (`jax.lax.ragged_dot`), or None:
+    the kernel where the program is lowered for a TPU (an ahead-of-time
+    compile for a described chip too), `ragged_dot` elsewhere."""
+    m = x.shape[0]
+    held = jnp.arange(m) < sizes.sum()
+
+    def ragged(x, w, sizes):
+        return jax.lax.ragged_dot(x, w if layer is None else w[layer], sizes)
+
+    def kernel(x, w, sizes, interpret=False):
+        from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+        if layer is not None:
+            stack, g = w.shape[:2]
+            w = w.reshape(stack * g, *w.shape[2:])
+            sizes = jax.lax.dynamic_update_slice(
+                jnp.zeros((stack * g,), sizes.dtype), sizes, (layer * g,))
+        tiling = gmm_tiling(m, w.shape[1], w.shape[2])
+        pad = -m % tiling[0]
+        xp = jnp.pad(x, ((0, pad), (0, 0))) if pad else x
+        return megablox.gmm(xp, w, sizes, x.dtype, tiling,
+                            interpret=interpret)[:m]
+
+    if impl is None:
+        y = jax.lax.platform_dependent(x, w, sizes, tpu=kernel,
+                                       default=ragged)
+    elif impl == "ragged_dot":
+        y = ragged(x, w, sizes)
+    elif impl in ("gmm", "gmm_interpret"):
+        y = kernel(x, w, sizes, interpret=impl == "gmm_interpret")
+    else:
+        raise ValueError(f"unknown grouped matmul {impl!r}; choices: "
+                         f"['gmm', 'gmm_interpret', 'ragged_dot']")
+    return jnp.where(held[:, None], y, jnp.zeros((), y.dtype))
+
+
+def _moe_dropless(p: Params, xt: jax.Array, cfg: ModelConfig, valid=None,
+                  impl: Optional[str] = None, layer=None
+                  ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Sort the (row, choice) pairs by expert -> grouped matmuls over the
+    experts that rows chose -> unsort and sum weighted by the gates ->
+    shared experts.  Pairs of rows that are not `valid` sort last, into no
+    group.  With `layer`, the expert weights are stacks over layers (see
+    `grouped_matmul`)."""
+    n, d = xt.shape
+    e, k = cfg.n_experts, cfg.experts_per_token
+    logits, probs, gates, experts = route(p, xt, cfg)
+    flat, sizes = _groups(experts, valid, e)
+    order = jnp.argsort(flat, stable=True)
+    xs = xt[order // k]                                        # (N*k, D)
+    h = jax.nn.silu(grouped_matmul(xs, p["w_gate"], sizes, impl, layer)) \
+        * grouped_matmul(xs, p["w_up"], sizes, impl, layer)
+    ys = grouped_matmul(h, p["w_down"], sizes, impl, layer)    # (N*k, D)
+    pairs = jnp.zeros_like(ys).at[order].set(ys).reshape(n, k, d)
+    y = jnp.einsum("nkd,nk->nd", pairs.astype(jnp.float32), gates)
+    y = y.astype(xt.dtype)
+    if "shared" in p:
+        y = y + mlp(p["shared"], xt)
+    return y, _aux_loss(logits, probs, experts, e), _counts(sizes)
 
 
 def _moe_core(p: Params, xt: jax.Array, cfg: ModelConfig,
-              capacity: int) -> Tuple[jax.Array, jax.Array]:
-    """Scatter dispatch -> expert FFNs -> gather combine, over flat tokens."""
+              capacity: int) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Scatter dispatch -> expert FFNs -> gather combine, over flat tokens.
+    Returns (y, aux, experts (N, k))."""
     n, d = xt.shape
     e, k = cfg.n_experts, cfg.experts_per_token
 
-    logits = (xt.astype(jnp.float32) @ p["router"])          # (N, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, expert_idx = jax.lax.top_k(probs, k)          # (N, k)
-    gate_vals = gate_vals / jnp.maximum(
-        gate_vals.sum(-1, keepdims=True), 1e-9)              # renormalize
+    logits, probs, gate_vals, expert_idx = route(p, xt, cfg)
 
     # position of each (token, slot) in its expert's queue, via cumsum over
     # the (N*k, E) one-hot — O(N*E) ints, no capacity dimension
@@ -129,7 +291,7 @@ def _moe_core(p: Params, xt: jax.Array, cfg: ModelConfig,
     yout = constrain(jnp.einsum("ecf,efd->ecd", h, p["w_down"]),
                      "model", None, None)
 
-    # gather back and combine with renormalized gates
+    # gather back and combine with the gates
     out_flat = yout.reshape(e * capacity, d)
     gathered = out_flat[jnp.minimum(slot, e * capacity - 1)]    # (N,k,D)
     w_comb = (gate_vals * keep).astype(gathered.dtype)
@@ -138,10 +300,4 @@ def _moe_core(p: Params, xt: jax.Array, cfg: ModelConfig,
 
     if "shared" in p:
         y = y + mlp(p["shared"], xt)
-
-    # Switch load-balance loss + z-loss
-    me = probs.mean(0)                                        # (E,)
-    ce = onehot.sum(1).mean(0)                                # fraction routed
-    aux = e * jnp.sum(me * ce) + 1e-3 * jnp.mean(
-        jnp.square(jax.nn.logsumexp(logits, axis=-1)))
-    return y, aux
+    return y, _aux_loss(logits, probs, expert_idx, e), expert_idx

@@ -102,7 +102,7 @@ def block_forward(p: Params, x: jax.Array, cfg: ModelConfig,
     h = rms_norm(x, p["ln2"], cfg.norm_eps)
     aux = jnp.zeros((), jnp.float32)
     if kind.ffn == "moe":
-        h, aux = moe_mod.moe_layer(p["ffn"], h, cfg)
+        h, aux, _ = moe_mod.moe_layer(p["ffn"], h, cfg)
     else:
         h = mlp(p["ffn"], h)
     return x + h, aux
@@ -123,7 +123,7 @@ def block_prefill(p: Params, x: jax.Array, cfg: ModelConfig, kind: BlockKind,
     h = rms_norm(x, p["ln2"], cfg.norm_eps)
     aux = jnp.zeros((), jnp.float32)
     if kind.ffn == "moe":
-        h, aux = moe_mod.moe_layer(p["ffn"], h, cfg)
+        h, aux, _ = moe_mod.moe_layer(p["ffn"], h, cfg)
     else:
         h = mlp(p["ffn"], h)
     return x + h, aux, kv
@@ -131,7 +131,10 @@ def block_prefill(p: Params, x: jax.Array, cfg: ModelConfig, kind: BlockKind,
 
 def block_decode(p: Params, x: jax.Array, cfg: ModelConfig, kind: BlockKind,
                  cache: Tuple[jax.Array, jax.Array], pos: jax.Array,
-                 start=None) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
+                 start=None, active=None, layer=None):
+    """-> (x, cache, counts): `counts` is the MoE layer's (experts hit,
+    pairs routed) of the `active` rows, None for a dense block.  With
+    `layer`, the routed experts' weights are stacks (see `moe_layer`)."""
     h = rms_norm(x, p["ln1"], cfg.norm_eps)
     if kind.attn == "mla":
         h, ck, cv = mla_mod.mla_decode(p["attn"], h, cfg, cache[0], cache[1],
@@ -142,11 +145,13 @@ def block_decode(p: Params, x: jax.Array, cfg: ModelConfig, kind: BlockKind,
                                      cache[0], cache[1], pos, start=start)
     x = x + h
     h = rms_norm(x, p["ln2"], cfg.norm_eps)
+    counts = None
     if kind.ffn == "moe":
-        h, _ = moe_mod.moe_layer(p["ffn"], h, cfg)
+        h, _, counts = moe_mod.moe_layer(p["ffn"], h, cfg, valid=active,
+                                         layer=layer)
     else:
         h = mlp(p["ffn"], h)
-    return x + h, (ck, cv)
+    return x + h, (ck, cv), counts
 
 
 # ------------------------------------------------------------------- model
@@ -154,6 +159,8 @@ class TransformerModel:
     """Decoder-only LM with the uniform Model API (see registry.py)."""
 
     def __init__(self, cfg: ModelConfig):
+        if cfg.rope_scaling is not None and cfg.attn_kind != "mla":
+            raise ValueError("rope_scaling is applied by mla attention only")
         self.cfg = cfg
         self.prologue, self.pattern, self.n_repeats = layer_program(cfg)
         dt = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}[cfg.dtype]
@@ -230,8 +237,17 @@ class TransformerModel:
         return nll.mean() + 0.01 * aux
 
     # ------------------------------------------------------------ serving
+    @property
+    def moe_layers(self) -> int:
+        """How many of the stack's layers route to experts."""
+        return (sum(k.ffn == "moe" for k in self.prologue)
+                + self.n_repeats * sum(k.ffn == "moe" for k in self.pattern))
+
     def cache_spec(self, batch: int, max_len: int):
-        """Shapes/dtypes of the KV cache pytree."""
+        """Shapes/dtypes of the KV cache pytree.  A stack with experts also
+        carries `moe`, int32 (moe_layers, 2): per MoE layer in layer
+        order, the experts hit and the (row, choice) pairs routed, summed
+        over the decode steps the cache has seen."""
         cfg = self.cfg
         if cfg.attn_kind == "mla":
             k_shape = (batch, max_len, cfg.kv_lora_rank)
@@ -245,7 +261,10 @@ class TransformerModel:
         pat = [jax.tree.map(
             lambda x: jnp.zeros((self.n_repeats,) + x.shape, x.dtype),
             per_block()) for _ in self.pattern]
-        return {"prologue": pro, "pattern": pat}
+        cache = {"prologue": pro, "pattern": pat}
+        if self.moe_layers:
+            cache["moe"] = jnp.zeros((self.moe_layers, 2), jnp.int32)
+        return cache
 
     def init_cache(self, batch: int, max_len: int):
         return self.cache_spec(batch, max_len)
@@ -258,9 +277,9 @@ class TransformerModel:
         kinds = self.prologue + self.pattern
         return all(k.attn != "mla" for k in kinds)
 
-    # decode_step accepts a (B,) pos vector (one timeline per batch slot)
-    # on the same attention paths that support pad masking
-    per_slot_pos = pad_aware
+    #: decode_step accepts a (B,) pos vector (one timeline per batch slot)
+    #: on both attention paths, GQA and MLA
+    per_slot_pos = True
 
     def _check_padded(self, start) -> None:
         if start is not None and not self.pad_aware:
@@ -300,38 +319,64 @@ class TransformerModel:
                            tuple(cache["pattern"])))
         x = rms_norm(x, params["ln_f"], cfg.norm_eps)
         logits = x[:, -1, :] @ params["unembed"]
-        return logits, {"prologue": new_pro, "pattern": list(new_pat)}
+        out = {"prologue": new_pro, "pattern": list(new_pat)}
+        if "moe" in cache:
+            out["moe"] = cache["moe"]
+        return logits, out
 
     def decode_step(self, params: Params, tokens: jax.Array, cache,
-                    pos: jax.Array, start=None) -> Tuple[jax.Array, Any]:
+                    pos: jax.Array, start=None, active=None
+                    ) -> Tuple[jax.Array, Any]:
         """tokens (B,1); pos: scalar int32 — position being written — or a
         (B,) vector when each batch slot runs its own timeline (continuous
         batching).  `start` (B,) masks cache entries before each row's
-        first real token (left-padded batches)."""
+        first real token (left-padded batches).  `active` (B,) bool marks
+        the rows that carry a request (None: all): the MoE layers route
+        only those, and the cache's `moe` counters add what they routed."""
         cfg = self.cfg
         self._check_padded(start)
-        if jnp.ndim(pos) == 1 and not self.per_slot_pos:
-            raise ValueError("per-slot pos vector requires gqa attention; "
-                             "this stack contains mla")
         x = params["embed"][tokens]
-        new_pro = []
+        new_pro, counts = [], []
         for p, kind, c in zip(params["prologue"], self.prologue,
                               cache["prologue"]):
-            x, c2 = block_decode(p, x, cfg, kind, c, pos, start=start)
+            x, c2, n = block_decode(p, x, cfg, kind, c, pos, start=start,
+                                    active=active)
             new_pro.append(c2)
+            if n is not None:
+                counts.append(n[None])
+
+        # the routed experts' stacks go into the layer loop whole, each
+        # layer picking its own by offset: a slice of them handed to the
+        # grouped matmul's Pallas call would be copied out every step
+        scanned, stacks = [], []
+        for p, kind in zip(params["pattern"], self.pattern):
+            ffn = dict(p["ffn"])
+            stacks.append({k: ffn.pop(k) for k in moe_mod.EXPERT_WEIGHTS}
+                          if kind.ffn == "moe" else {})
+            scanned.append(dict(p, ffn=ffn))
 
         def scan_body(x, scanned):
-            layer_params, layer_cache = scanned
-            new_cache = []
-            for p, kind, c in zip(layer_params, self.pattern, layer_cache):
-                x, c2 = block_decode(p, x, cfg, kind, c, pos, start=start)
+            layer_params, layer_cache, i = scanned
+            new_cache, ns = [], []
+            for p, kind, c, held in zip(layer_params, self.pattern,
+                                        layer_cache, stacks):
+                p = dict(p, ffn={**p["ffn"], **held})
+                x, c2, n = block_decode(p, x, cfg, kind, c, pos, start=start,
+                                        active=active,
+                                        layer=i if held else None)
                 new_cache.append(c2)
-            return x, tuple(new_cache)
+                if n is not None:
+                    ns.append(n)
+            return x, (tuple(new_cache), jnp.stack(ns) if ns else None)
 
-        x, new_pat = jax.lax.scan(
-            scan_body, x, (tuple(params["pattern"]),
-                           tuple(cache["pattern"])))
+        layers = jnp.arange(self.n_repeats) if any(stacks) else None
+        x, (new_pat, pat_counts) = jax.lax.scan(
+            scan_body, x, (tuple(scanned), tuple(cache["pattern"]), layers))
         x = rms_norm(x, params["ln_f"], cfg.norm_eps)
         logits = x @ params["unembed"]
-        return logits[:, 0, :], {"prologue": new_pro,
-                                 "pattern": list(new_pat)}
+        out = {"prologue": new_pro, "pattern": list(new_pat)}
+        if "moe" in cache:
+            if pat_counts is not None:                # (repeats, moe, 2)
+                counts.append(pat_counts.reshape(-1, 2))
+            out["moe"] = cache["moe"] + jnp.concatenate(counts)
+        return logits[:, 0, :], out

@@ -9,11 +9,12 @@ with iteration-level scheduling over a fixed pool of **slots**:
   * every step runs ONE jitted `decode_step` at a fixed (max_batch, 1)
     shape — a single XLA program for the whole run;
   * each slot carries its own timeline (`pos` is a per-slot vector, see
-    `models/layers.attention_decode`): a slot still consuming its prompt
-    feeds the next prompt token (this *is* chunked prefill, interleaved
-    token-by-token with in-flight decodes — a long prompt never stalls
-    anyone), a decoding slot feeds the token it just sampled, and a free
-    slot feeds a masked dummy;
+    `models/layers.attention_decode` and `models/mla.mla_decode`): a slot
+    still consuming its prompt feeds the next prompt token (this *is*
+    chunked prefill, interleaved token-by-token with in-flight decodes —
+    a long prompt never stalls anyone), a decoding slot feeds the token
+    it just sampled, and a free slot feeds a masked dummy (which a model
+    with experts routes to no expert);
   * requests join a free slot the step they arrive (admission queue
     ordered by `Request.arrival_s`) and leave the step they finish —
     the next queued request takes over the slot immediately.
@@ -173,6 +174,11 @@ class SchedulerReport:
     bucket_steps: Dict[str, int]
     replan_events: List[ReplanEvent]
     clock: str
+    #: a model with experts: per MoE layer, in layer order, the experts
+    #: that the active slots' rows chose and the (row, choice) pairs they
+    #: routed, each summed over the run's steps (None without experts)
+    moe_experts_hit: Optional[List[int]] = None
+    moe_rows: Optional[List[int]] = None
 
     @property
     def tokens_per_s(self) -> float:
@@ -271,9 +277,11 @@ class ContinuousScheduler:
     """Iteration-level scheduler over a fixed slot pool (see module doc).
 
     `model` must support per-slot position vectors
-    (`model.per_slot_pos`, the GQA attention path) — each slot runs its
-    own timeline in the shared cache, which is what makes join/evict
-    correct without any re-prefill or padding.
+    (`model.per_slot_pos`: the decoder stacks, GQA and MLA) — each slot
+    runs its own timeline in the shared cache, which is what makes
+    join/evict correct without any re-prefill or padding.  A model with
+    experts (`model.moe_layers`) is told which rows carry a request each
+    step, and its expert counters are read once, after the run.
     """
 
     def __init__(self, cfg, model, params, *,
@@ -286,8 +294,8 @@ class ContinuousScheduler:
         if not getattr(model, "per_slot_pos", False):
             raise ValueError(
                 "ContinuousScheduler needs per-slot position support "
-                "(model.per_slot_pos — the gqa attention path); recurrent "
-                "and MLA stacks serve through the fixed-batch "
+                "(model.per_slot_pos — the gqa and mla decoder stacks); "
+                "recurrent stacks serve through the fixed-batch "
                 "ServingEngine instead")
         self.cfg = cfg
         self.model = model
@@ -306,6 +314,7 @@ class ContinuousScheduler:
         # the cache is donated: each step's output replaces it, so one KV
         # cache is live at a time, not two
         self._decode = jax.jit(model.decode_step, donate_argnums=(2,))
+        self._routes = bool(getattr(model, "moe_layers", 0))
         # per-bucket drift state (portfolio mode)
         self._monitors: Dict[Any, Any] = {}
         self._recent_reports: Dict[Any, List[Any]] = {}
@@ -491,9 +500,13 @@ class ContinuousScheduler:
                             temps[i] = s.req.temperature
                         pos[i] = s.pos
                     toks_d, pos_d = jnp.asarray(toks), jnp.asarray(pos)
+                    args = (self.params, toks_d, cache, pos_d)
+                    if self._routes:
+                        live = np.zeros((cfg.max_batch,), bool)
+                        live[active] = True
+                        args += (None, jnp.asarray(live))
                 with trace.span("repro.sched.decode"):
-                    logits, cache = self._decode(self.params, toks_d, cache,
-                                                 pos_d)
+                    logits, cache = self._decode(*args)
                 # sampling temperature applies only to rows past their
                 # prompt; rows mid-prefill (and free rows) stay greedy so
                 # they never consume rng — admission order cannot shift
@@ -542,12 +555,16 @@ class ContinuousScheduler:
                     with trace.span("repro.sched.fidelity"):
                         self._observe_fidelity(bucket, compiled, now, steps)
 
+        hit = rows = None
+        if self._routes:
+            moe = np.asarray(cache["moe"])
+            hit, rows = moe[:, 0].tolist(), moe[:, 1].tolist()
         return SchedulerReport(
             completions=completions, stats=stats,
             duration_s=now - start_s, steps=steps,
             total_tokens=total_tokens, bucket_switches=bucket_switches,
             bucket_steps=bucket_steps, replan_events=self.replan_events,
-            clock=cfg.clock)
+            clock=cfg.clock, moe_experts_hit=hit, moe_rows=rows)
 
 
 class FixedBatchReference:

@@ -26,6 +26,7 @@ from repro.kernels.split_matmul import split_matmul_op  # noqa: E402
 from repro.kernels.ssd_chunk import ssd_chunk_op  # noqa: E402
 from repro.kernels.winograd_conv.winograd_conv import (  # noqa: E402
     winograd_conv2d)
+from repro.models.moe import grouped_matmul  # noqa: E402
 
 
 @pytest.fixture(scope="module")
@@ -78,12 +79,24 @@ def _ssd_chunk(dt):
              ((1, t, h), dt), ((h,), dt), ((1, h, hd, n), dt)])
 
 
+def _grouped_matmul(dt):
+    # deepseek-v2-lite routed experts' gate projection: 72 (row, choice)
+    # pairs, one layer (as the layer loop gives it) of a stack of 8 MoE
+    # layers of 64 experts; the kernel is chosen by the platform the
+    # program is lowered for
+    return (lambda x, w, sizes, layer: grouped_matmul(x, w, sizes,
+                                                      layer=layer),
+            [((72, 2048), dt), ((8, 64, 2048, 1408), dt),
+             ((64,), jnp.int32), ((), jnp.int32)])
+
+
 KERNELS = {"split_matmul": _linear, "winograd": _winograd,
-           "decode_attention": _decode_attention, "ssd_chunk": _ssd_chunk}
+           "decode_attention": _decode_attention, "ssd_chunk": _ssd_chunk,
+           "grouped_matmul": _grouped_matmul}
 #: each kernel's stable `name=`, which names its custom call in a profile
 CALL_NAMES = {"split_matmul": "split_matmul", "winograd": "winograd_conv",
               "decode_attention": "decode_attention",
-              "ssd_chunk": "ssd_chunk"}
+              "ssd_chunk": "ssd_chunk", "grouped_matmul": "gmm"}
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
