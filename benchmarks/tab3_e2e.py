@@ -8,9 +8,9 @@ Pixel 4 / Pixel 5 / Moto 2022 / OnePlus 11.
 `repro.compile` facade and reports executed-vs-predicted latency per op
 (predictions model the phone, execution runs on this host — the per-op
 ratio's spread is the fidelity signal), then runs EVERY network both ways
-through the executor — per-node walk vs fused segment walk — and reports
-the wall-time comparison (fused should never lose: same computation,
-strictly fewer dispatches and device syncs).
+through the executor — per-node walk vs fused walk (one program, one
+sync) — and reports the wall-time comparison (fused should never lose:
+same computation, strictly fewer dispatches and device syncs).
 """
 from __future__ import annotations
 
@@ -89,7 +89,7 @@ def _execute_rows(compiled, dev: str, name: str, chain: bool) -> list:
 
 
 def _fused_rows(compiled, dev: str, name: str) -> list:
-    """Fused (segment walk) vs unfused (per-node walk) wall time for one
+    """Fused (one-program walk) vs unfused (per-node walk) wall time for one
     network — best of 2 timed runs each, after the shared warmup."""
     best = {}
     for fused in (False, True):
@@ -99,7 +99,7 @@ def _fused_rows(compiled, dev: str, name: str) -> list:
     ru, rf = best[False], best[True]
     speedup = ru.wall_us / rf.wall_us if rf.wall_us > 0 else float("inf")
     print(f"# {name}: fused {rf.wall_us / 1e3:.1f} ms "
-          f"({len(rf.segment_wall_us)} segments, {rf.sync_points} syncs) "
+          f"({rf.segments} segments, {rf.sync_points} syncs) "
           f"vs unfused {ru.wall_us / 1e3:.1f} ms ({ru.sync_points} syncs) "
           f"-> {speedup:.2f}x")
     return [
@@ -107,7 +107,7 @@ def _fused_rows(compiled, dev: str, name: str) -> list:
                 f"sync={ru.sync_points},reshard={ru.reshard_points},"
                 f"elided={ru.elided}"),
         csv_row(f"tab3_exec_{dev}_{name}_fused", rf.wall_us,
-                f"segments={len(rf.segment_wall_us)},"
+                f"segments={rf.segments},"
                 f"sync={rf.sync_points},reshard={rf.reshard_points},"
                 f"elided={rf.elided},speedup={speedup:.2f}x"),
     ]

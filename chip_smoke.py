@@ -76,7 +76,7 @@ def _rel_err(y, ref) -> float:
 def _kernel_count(exe, report) -> int:
     """`tpu_custom_call`s in the programs of the nodes the walk ran
     exclusively: each runs its registry Pallas lowering, on its own
-    (unfused walk) or inside a segment program (fused walk)."""
+    (unfused walk) or inside the walk's one program (fused walk)."""
     import jax
 
     from repro.kernels import registry
@@ -130,7 +130,7 @@ def plan_execute(args) -> None:
         print(f"[plan->execute] {name}: max rel err vs oracle: unfused "
               f"{err_u:.3e}, fused {err_f:.3e} (bound {EXEC_TOL:g}); "
               f"{kernels} tpu_custom_call in the executed exclusive nodes' "
-              f"programs; {len(rep_f.segment_wall_us)} segments in the fused walk")
+              f"programs; {rep_f.segments} segments in the fused walk")
         _check(err_u <= EXEC_TOL and err_f <= EXEC_TOL,
                f"{name}: walk differs from the oracle beyond {EXEC_TOL}")
         if args.four_chips:

@@ -450,11 +450,14 @@ class CompiledNetwork:
             use_pallas: bool = False):
         """Execute the plan once; returns the output activation.
 
-        `fused=True` takes the segment walk (one jitted program per fused
-        segment, bit-identical outputs); the per-node walk is the
-        `fused=False` reference.  The per-op `ExecutionReport` of this run
-        is kept on `self.last_report` (and `profile()` is the report-first
-        spelling).
+        `fused=True` takes the fused walk: the whole plan as one jitted
+        program, one dispatch and one device sync per inference, outputs
+        bit-identical to the per-node walk on the CPU.  Its per-node
+        records carry the walk's wall spread pro rata by predicted
+        latency; the per-node walk (`fused=False`) is the reference that
+        times each op.  The
+        per-op `ExecutionReport` of this run is kept on `self.last_report`
+        (and `profile()` is the report-first spelling).
         """
         exe = self.executor(dtype=dtype, seed=seed, use_pallas=use_pallas)
         y, report = exe.run(x, chain=chain, warmup=warmup, fused=fused)
@@ -487,7 +490,7 @@ class CompiledNetwork:
 
         Returns the `ExecutionReport`; the accumulated records are what
         `recalibrate()` fits on.  Fused runs record with
-        `source="fused"` (segment wall attributed pro-rata) and feed the
+        `source="fused"` (the walk's wall attributed pro-rata) and feed the
         same calibration fit.
         """
         report = self.profile(x, dtype=dtype, chain=chain, warmup=warmup,

@@ -204,7 +204,7 @@ def _cmd_execute(args) -> int:
         rep_fused = compiled.last_report
         identical = (np.asarray(y_fused).tobytes()
                      == np.asarray(y_unfused).tobytes())
-        n_seg = len(rep_fused.segment_wall_us)
+        n_seg = rep_fused.segments
         print(f"  fused: {n_seg} segments, {rep_fused.sync_points} syncs "
               f"(vs {rep_unfused.sync_points} unfused), outputs "
               f"{'bit-identical' if identical else 'DIVERGED'}")
@@ -419,8 +419,9 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     p_exec.add_argument("--per-op", action="store_true",
                         help="print one line per executed unit")
     p_exec.add_argument("--fused", action="store_true",
-                        help="also run the fused segment walk and compare "
-                             "it byte-for-byte against the per-node walk")
+                        help="also run the fused walk (one program) and "
+                             "compare it byte-for-byte against the per-node "
+                             "walk")
 
     p_cal = sub.add_parser(
         "calibrate", help="record executions, fit a latency calibrator, "

@@ -42,8 +42,8 @@ MEASUREMENT_SCHEMA_VERSION = 1
 #: record sources
 SOURCE_EXECUTOR = "executor"      # wall-clock timed plan execution
 SOURCE_SIMULATOR = "simulator"    # analytic device-model measurement
-SOURCE_FUSED = "fused"            # segment-walk execution: per-node wall is
-                                  # the segment wall attributed pro-rata by
+SOURCE_FUSED = "fused"            # fused-walk execution: per-node wall is
+                                  # the walk's wall attributed pro-rata by
                                   # predicted latency
 
 #: execution modes (executor) + the simulator's pseudo-mode

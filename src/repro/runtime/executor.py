@@ -36,21 +36,31 @@ executor re-materializes the declared shape deterministically (tile +
 crop), and the unsplit oracle (`run_oracle`) applies the identical
 adaptation — so executed plans are testable against the oracle end to end.
 
-Every node execution is timed into a `repro.measure.MeasurementRecord` —
+Two walks execute a plan.  The per-node walk (`run(fused=False)`, the
+default) dispatches node by node and syncs after each: it is the
+reference that times each op.  The fused walk (`run(fused=True)`) runs the
+whole plan as ONE jitted program per input shape (`repro.runtime.segments`)
+with ONE `block_until_ready`, bit-identical to the per-node walk on the
+CPU (on the chip XLA may fuse across segments: see `segments._fence`).
+
+Every node execution is recorded as a `repro.measure.MeasurementRecord` —
 the one schema shared with the simulator and the predictor training sets —
 and the resulting `ExecutionReport` pairs executed wall time with the
 plan's predicted latency per op (what `MeasurementStore`/`Calibrator`
-consume for online replanning).  Note the predictions model a *phone*, the
+consume for online replanning).  The per-node walk measures each op; the
+fused walk measures the walk and spreads its wall over the ops pro rata
+by predicted latency.  Note the predictions model a *phone*, the
 execution runs on *this host* — the report tracks the ratio's stability
 across ops, not its absolute value.
 
 The timings are read from the walk's spans (`repro.measure.trace`), which
 a profiler session also records on its clock: `repro.exec.run` bounds one
-walk; `repro.exec.segment` one fused segment (one node in the per-node
-walk), from the call that enqueues it to the end of its sync; and
-`repro.exec.sync` each `block_until_ready` — inside its segment, and for
-the per-node walk's terminal gather, directly inside the run.  A
-segment's time less its sync is the host's dispatch time.
+walk; `repro.exec.segment` the fused walk's one program (`index` 0, mode
+`"fused"`) or one node in the per-node walk, from the call that enqueues
+it to the end of its sync; and `repro.exec.sync` each
+`block_until_ready` — inside its segment, and for the per-node walk's
+terminal gather, directly inside the run.  A segment's time less its sync
+is the host's dispatch time.
 """
 from __future__ import annotations
 
@@ -96,11 +106,13 @@ class ExecutionReport:
     timings: List[MeasurementRecord]
     reshard_points: int
     elided: int
-    fused: bool = False          # segment walk (True) vs per-node walk
+    fused: bool = False          # fused walk (True) vs per-node walk
     sync_points: int = 0         # device syncs issued by the walk
-    #: fused runs: per-segment wall, in partition order (the per-node
-    #: wall_us of member records is this attributed pro-rata by pred_us)
+    #: fused runs: the walk's wall, one entry (the per-node wall_us of the
+    #: records is this attributed pro-rata by pred_us); it duplicates
+    #: `wall_us` and stays only while the benchmark's span test sums it
     segment_wall_us: List[float] = dataclasses.field(default_factory=list)
+    segments: int = 0            # fused runs: the plan's segment count
 
     @property
     def wall_us(self) -> float:
@@ -142,7 +154,7 @@ class ExecutionReport:
             ratio = f"(x{self.wall_us / self.predicted_us:.2f})"
         else:
             ratio = "(ratio n/a: no predicted latency)"
-        seg = (f"{len(self.segment_wall_us)} segments "
+        seg = (f"{self.segments} segments "
                f"({self.sync_points} syncs), " if self.fused else "")
         return (f"fidelity: {n} units ({self.count('coexec')} co-executed, "
                 f"{self.count('exclusive')} exclusive, "
@@ -162,6 +174,7 @@ class ExecutionReport:
                 "fused": self.fused,
                 "sync_points": self.sync_points,
                 "segment_wall_us": list(self.segment_wall_us),
+                "segments": self.segments,
                 "wall_us": self.wall_us,
                 "predicted_us": self.predicted_us,
                 "timings": [t.to_json() for t in self.timings]}
@@ -176,7 +189,8 @@ class ExecutionReport:
             reshard_points=d["reshard_points"], elided=d["elided"],
             fused=d.get("fused", False),
             sync_points=d.get("sync_points", 0),
-            segment_wall_us=list(d.get("segment_wall_us", [])))
+            segment_wall_us=list(d.get("segment_wall_us", [])),
+            segments=d.get("segments", 0))
 
 
 # ------------------------------------------------------------- activations
@@ -269,9 +283,9 @@ class PlanExecutor:
         self.interpret = interpret
         self.last_report: Optional[ExecutionReport] = None
         self._warmed: set = set()      # (chain, fused) keys executed once
-        # segment programs, memoized per input shape (chaining is
-        # shape-exact, so the fused layout depends on the input shape)
-        self._programs: Dict[Tuple[int, ...], list] = {}
+        # the fused walk and its records' fields, memoized per input shape
+        # (chaining is shape-exact, so the layout depends on the shape)
+        self._programs: Dict[Tuple[int, ...], Any] = {}
 
         rng = np.random.default_rng(seed)
         self.params: List[Optional[jax.Array]] = []
@@ -399,15 +413,44 @@ class PlanExecutor:
         return act.shape == tuple(registry.get(spec.unit).input_shape(op))
 
     # ------------------------------------------------------------ segments
-    def segment_programs(self, x_shape: Optional[Tuple[int, ...]] = None):
-        """The compiled `SegmentProgram` list for input shape `x_shape`
-        (default: the input template's shape).  Memoized per shape."""
+    def walk_program(self, x_shape: Optional[Tuple[int, ...]] = None):
+        """The fused walk (`WalkProgram`: one jitted program over every
+        segment) for input shape `x_shape` (default: the input template's
+        shape).  Memoized per shape."""
+        return self._walk(x_shape)[0]
+
+    def _walk(self, x_shape: Optional[Tuple[int, ...]] = None):
+        """(`WalkProgram`, per-node record fields, per-node wall shares)
+        for `x_shape`, built once per shape: a run only scales the shares
+        by its wall."""
         if x_shape is None:
             x_shape = tuple(self.input_template().shape)
         x_shape = tuple(x_shape)
         if x_shape not in self._programs:
-            from repro.runtime.segments import compile_segments
-            self._programs[x_shape] = compile_segments(self, x_shape)
+            from repro.runtime.segments import compile_walk
+            walk = compile_walk(self, x_shape)
+            pos = {n.id: i for i, n in enumerate(self.graph)}
+            prov = self.plan.provenance
+            fields, preds = [], []
+            for sp in walk.segments:
+                for nid in sp.node_ids:
+                    spec = self.specs[pos[nid]]
+                    preds.append(spec.pred_total_us)
+                    fields.append(dict(
+                        index=pos[nid], unit=spec.unit,
+                        label=spec_label(spec), mode=sp.modes[nid],
+                        c_fast=spec.c_fast, c_slow=spec.c_slow,
+                        chained_input=sp.chained[nid],
+                        gathered_output=sp.gathered[nid],
+                        pred_us=spec.pred_total_us, op=spec.op,
+                        source=SOURCE_FUSED, device=prov.device,
+                        host=platform.node(), plan_key=self.plan.key,
+                        network_fingerprint=prov.network_fingerprint,
+                        node_id=nid, segment=sp.index))
+            total = sum(preds)
+            shares = [p / total if total > 0.0 else 1.0 / len(preds)
+                      for p in preds]
+            self._programs[x_shape] = (walk, fields, shares)
         return self._programs[x_shape]
 
     # ----------------------------------------------------------------- run
@@ -430,11 +473,12 @@ class PlanExecutor:
         CLIs and `tab3 --execute` warm up by default; equivalence tests
         skip it for speed.
 
-        `fused=True` takes the segment walk instead of the per-node walk:
-        the plan's partition lowered into one jitted program per fused
-        segment (see `repro.runtime.segments`), bit-identical outputs,
-        one device sync per segment.  The per-node walk stays as the
-        `fused=False` reference.
+        `fused=True` takes the fused walk instead of the per-node walk:
+        the plan's whole partition lowered into one jitted program per
+        input shape (see `repro.runtime.segments`), one dispatch and one
+        device sync per inference, outputs bit-identical on the CPU.  The
+        per-node walk stays as the `fused=False` reference that times
+        each op.
         """
         if fused and not chain:
             raise ValueError(
@@ -600,90 +644,41 @@ class PlanExecutor:
 
     def _execute_fused(self, x: Optional[jax.Array] = None
                        ) -> Tuple[jax.Array, ExecutionReport]:
-        """The segment walk: one jitted program (and one device sync) per
-        fused segment, eager singletons for pool/exclusive nodes.
+        """The fused walk: the whole plan as one jitted program, one
+        dispatch and one device sync.
 
-        A segment's wall time cannot be split per member by measurement —
-        the whole point is that the members no longer sync — so each
-        member record carries the segment wall attributed **pro-rata by
-        predicted latency** (equal shares when the segment has no
-        prediction), flagged `source="fused"` and tagged with its segment
-        index.  Summing member walls recovers the segment wall exactly,
-        so report totals stay comparable with the per-node walk, and
-        `Calibrator.fit` consumes the records unchanged.
+        The walk's wall time cannot be split per node by measurement — the
+        whole point is that the nodes no longer sync — so each node's
+        record carries the walk's wall attributed **pro-rata by predicted
+        latency** (equal shares when nothing is predicted), flagged
+        `source="fused"` and tagged with its segment index.  Summing the
+        records recovers the walk's wall exactly, so report totals stay
+        comparable with the per-node walk, and `Calibrator.fit` consumes
+        the records unchanged.
         """
-        x0: jax.Array = (self.input_template() if x is None
-                         else jnp.asarray(x, self.dtype))
-        programs = self.segment_programs(tuple(x0.shape))
-        pos = {n.id: i for i, n in enumerate(self.graph)}
-        acts: Dict[Optional[str], jax.Array] = {None: x0}
-        timings: List[MeasurementRecord] = []
-        segment_wall: List[float] = []
-        reshard = elided = 0
-        host = platform.node()
+        if x is None:
+            x0: jax.Array = self.input_template()
+        elif isinstance(x, jax.Array) and x.dtype == self.dtype:
+            x0 = x                        # as `jnp.asarray` would return it
+        else:
+            x0 = jnp.asarray(x, self.dtype)
+        walk, fields, shares = self._walk(tuple(x0.shape))
+        with trace.span("repro.exec.segment", index=0, mode="fused") as seg:
+            y = walk.fn(x0, walk.weights)
+            with trace.span("repro.exec.sync"):
+                jax.block_until_ready(y)
+        wall = seg.elapsed_s * 1e6
         prov = self.plan.provenance
-
-        for sp in programs:
-            mode = "fused" if sp.fn is not None else sp.modes[sp.node_ids[0]]
-            with trace.span("repro.exec.segment", index=sp.index,
-                            mode=mode) as seg:
-                if sp.fn is not None:
-                    out = sp.fn([acts[s] for s in sp.ext_inputs], sp.weights)
-                else:
-                    nid = sp.node_ids[0]
-                    spec = self.specs[pos[nid]]
-                    src_val = acts[sp.ext_inputs[0]]
-                    if mode == "pool":
-                        out = self._pool(src_val, spec.pool_bytes)
-                    elif mode == "coexec":
-                        # typed-axis split: runs as an eager exclusive-
-                        # segment singleton so its shard_map program is the
-                        # sole compilation unit (fp32 bit-identity vs the
-                        # oracle); kv-block additionally merges/materializes
-                        # internally
-                        split, packed = self._splits[pos[nid]]
-                        low = registry.get_split_lowering(spec.unit,
-                                                          spec.axis)
-                        out = low.run(self._adapt(src_val, spec), packed,
-                                      split, self.mesh, spec.op, spec.c_fast,
-                                      use_pallas=self.use_pallas,
-                                      interpret=self.interpret,
-                                      tile=spec.tile)
-                    else:
-                        out = self._dense(self._adapt(src_val, spec),
-                                          self.params[pos[nid]], spec)
-                with trace.span("repro.exec.sync"):
-                    jax.block_until_ready(out)
-            wall = seg.elapsed_s * 1e6
-            segment_wall.append(wall)
-            reshard += sp.gathers
-            elided += sp.elided
-            # convexity: only a segment's last node is consumed downstream
-            acts[sp.node_ids[-1]] = out
-            preds = [self.specs[pos[n]].pred_total_us for n in sp.node_ids]
-            total = sum(preds)
-            for nid, pred in zip(sp.node_ids, preds):
-                spec = self.specs[pos[nid]]
-                share = (wall * pred / total if total > 0.0
-                         else wall / len(preds))
-                timings.append(MeasurementRecord(
-                    index=pos[nid], unit=spec.unit, label=spec_label(spec),
-                    mode=sp.modes[nid], c_fast=spec.c_fast,
-                    c_slow=spec.c_slow, chained_input=sp.chained[nid],
-                    gathered_output=sp.gathered[nid], wall_us=share,
-                    pred_us=spec.pred_total_us, op=spec.op,
-                    source=SOURCE_FUSED, device=prov.device, host=host,
-                    plan_key=self.plan.key,
-                    network_fingerprint=prov.network_fingerprint,
-                    node_id=nid, segment=sp.index))
-
-        y = acts[self.graph.output.id]
         report = ExecutionReport(
             device=prov.device,
             network_fingerprint=prov.network_fingerprint,
-            chain=True, split_capable=self.split_capable, timings=timings,
-            reshard_points=reshard, elided=elided, fused=True,
-            sync_points=len(programs), segment_wall_us=segment_wall)
+            chain=True, split_capable=self.split_capable,
+            timings=[MeasurementRecord(wall_us=wall * share, **kw)
+                     for kw, share in zip(fields, shares)],
+            reshard_points=sum(sp.gathers for sp in walk.segments),
+            elided=sum(sp.elided for sp in walk.segments), fused=True,
+            sync_points=1, segment_wall_us=[wall],
+            segments=len(walk.segments))
         return y, report
 
     def run_oracle(self, x: Optional[jax.Array] = None) -> jax.Array:

@@ -259,8 +259,9 @@ _SPLIT_PROG = textwrap.dedent("""
                     ("tiny_hybrid", from_model("tiny_hybrid", blocks=2,
                                                cache_len=512))]:
         plan = forced(g)
-        # typed-axis nodes are never inside fused segments (compilation-
-        # unit discipline: one jitted shard_map program per split node)
+        # typed-axis nodes are never inside fused segments: each is a
+        # singleton segment of the walk, its shard_map program reading
+        # and publishing materialized values only
         typed = {nid for nid, d in plan.decisions_by_node.items()
                  if d.axis not in ("channel", "none")}
         assert typed, name
@@ -275,6 +276,8 @@ _SPLIT_PROG = textwrap.dedent("""
         np.testing.assert_allclose(np.asarray(y_u), np.asarray(y_o),
                                    rtol=1e-5, atol=1e-5, err_msg=name)
         assert rep_u.count("coexec") > 0, name
+        # the whole walk, typed-axis singletons included, syncs once
+        assert rep_f.sync_points == 1 < rep_u.sync_points, name
         print(name, "exec ok:", len(typed), "typed-axis node(s)")
     print("DECODE_EXEC_OK")
 """)

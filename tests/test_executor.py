@@ -311,8 +311,9 @@ _SUBPROCESS_PROG = textwrap.dedent("""
     ref = PlanExecutor(plan, mesh=mesh).run_oracle()
     exe = PlanExecutor(plan, mesh=mesh, use_pallas=True, interpret=True)
     y_u, rep_u = exe.run()
-    y_f, _ = exe.run(fused=True)
+    y_f, rep_f = exe.run(fused=True)
     assert rep_u.count("exclusive") > 0 and rep_u.count("coexec") > 0
+    assert rep_f.sync_points == 1 < rep_u.sync_points
     for y in (y_u, y_f):
         np.testing.assert_allclose(np.asarray(y), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)

@@ -1,7 +1,7 @@
 """Differential test harness for the segment compiler.
 
-The fused segment walk (`PlanExecutor.run(fused=True)` lowering each
-same-mesh segment into one jitted program, see `repro.runtime.segments`)
+The fused walk (`PlanExecutor.run(fused=True)` lowering the plan's whole
+segment partition into one jitted program, see `repro.runtime.segments`)
 is locked against two references: the per-node walk (`fused=False`) and
 the unsplit oracle (`run_oracle`) — outputs must agree bit-for-bit, and
 the partition (`Graph.segments`) must cut exactly where the unfused walk
@@ -237,7 +237,9 @@ def test_random_residual_dag_fused_equals_unfused_and_oracle(
     assert np.asarray(y_f).tobytes() == np.asarray(y_u).tobytes()
     assert np.asarray(y_f).tobytes() == np.asarray(y_o).tobytes()
     assert rep_f.fused and not rep_u.fused
-    assert rep_f.sync_points == len(rep_f.segment_wall_us)
+    # one program, one sync, one wall for the whole walk
+    assert rep_f.sync_points == len(rep_f.segment_wall_us) == 1
+    assert rep_f.segments == len(exe.walk_program().segments)
     assert rep_f.sync_points <= rep_u.sync_points
     assert len(rep_f.timings) == len(rep_u.timings) == len(g.nodes)
     # the partition indices on the records cover the partition in order
@@ -262,7 +264,7 @@ def test_pool_boundaries_differential():
     y_o = exe.run_oracle()
     assert np.asarray(y_f).tobytes() == np.asarray(y_u).tobytes()
     assert np.asarray(y_f).tobytes() == np.asarray(y_o).tobytes()
-    kinds = [p.kind for p in exe.segment_programs()]
+    kinds = [p.kind for p in exe.walk_program().segments]
     assert SEGMENT_POOL in kinds
     assert rep_f.count("pool") == rep_u.count("pool") == 1
 
@@ -348,7 +350,7 @@ _SPLIT_PROG = textwrap.dedent("""
         plan, decisions = forced(g)
         exe = PlanExecutor(plan, mesh=mesh)
         assert exe.split_capable
-        progs = exe.segment_programs()
+        progs = exe.walk_program().segments
         fused = [p for p in progs if p.kind == "fused"]
         assert 0 < len(progs) < 10 and fused, name
         # acceptance: a fused segment issues EXACTLY ONE gather — at its
@@ -362,9 +364,10 @@ _SPLIT_PROG = textwrap.dedent("""
         assert np.asarray(y_f).tobytes() == np.asarray(y_u).tobytes(), name
         np.testing.assert_allclose(np.asarray(y_f), np.asarray(y_o),
                                    rtol=2e-5, atol=2e-5)
-        # strictly fewer device syncs than the per-node walk
+        # strictly fewer device syncs than the per-node walk: one for
+        # the whole walk, whatever the number of segments
         assert rep_f.sync_points < rep_u.sync_points, name
-        assert rep_f.sync_points == len(progs)
+        assert rep_f.sync_points == 1 and rep_f.segments == len(progs)
         # both walks reshard at the same points and elide the same edges
         assert rep_f.reshard_points == rep_u.reshard_points, name
         assert rep_f.elided == rep_u.elided, name
@@ -415,8 +418,8 @@ def mux_predictors():
 def test_fused_records_roundtrip_store_calibrate_replan(mux_predictors,
                                                         tmp_path):
     """Fused plan -> MeasurementStore -> Calibrator.fit -> replan(), end
-    to end on source="fused" records; per-segment attribution sums back
-    to the segment wall."""
+    to end on source="fused" records; the pro-rata attribution sums back
+    to the walk's wall, and the records keep their segment indices."""
     import repro
 
     cache = PlanCache(tmp_path / "plans")
@@ -436,8 +439,10 @@ def test_fused_records_roundtrip_store_calibrate_replan(mux_predictors,
         for t in rep.timings:
             assert t.source == "fused" and t.segment >= 0
             by_seg[t.segment] += t.wall_us
-        for k, wall in enumerate(rep.segment_wall_us):
-            assert by_seg[k] == pytest.approx(wall, rel=1e-9, abs=1e-6), k
+        assert sorted(by_seg) == list(range(rep.segments))
+        (wall,) = rep.segment_wall_us
+        assert sum(by_seg.values()) == pytest.approx(wall, rel=1e-9,
+                                                     abs=1e-6)
 
     records = store.load(compiled.key)
     assert len(records) == 2 * len(compiled.plan.schedule)

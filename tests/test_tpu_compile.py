@@ -9,6 +9,12 @@ what interpret mode cannot: block shapes the TPU lowering refuses,
 primitives it has no rule for, and layouts Mosaic cannot build.  Nothing
 runs, so they say nothing about results or times.
 
+The executor's fused walk is compiled the same way, for ResNet-18 and
+VGG-16's convolutions at 224 x 224 and for a small decoder: on the chip
+the walk is one program with its kernels under their stable names, and no
+fence between segments is left in it (on the CPU each segment's output
+goes through a conditional, `repro.runtime.segments._fence`).
+
 The topology is described only inside the module fixture: only one process
 at a time may load the TPU library, and pytest-xdist workers each import
 this file.
@@ -27,6 +33,13 @@ from repro.kernels.ssd_chunk import ssd_chunk_op  # noqa: E402
 from repro.kernels.winograd_conv.winograd_conv import (  # noqa: E402
     winograd_conv2d)
 from repro.models.moe import grouped_matmul  # noqa: E402
+from repro.core import networks  # noqa: E402
+from repro.core.partitioner import PartitionDecision  # noqa: E402
+from repro.graph.frontends import from_model  # noqa: E402
+from repro.graph.ir import from_units  # noqa: E402
+from repro.runtime.executor import PlanExecutor  # noqa: E402
+from repro.runtime.plan import (CoexecPlan, PlanProvenance,  # noqa: E402
+                                build_graph_schedule, segments_json)
 
 
 @pytest.fixture(scope="module")
@@ -110,3 +123,60 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, dtype):
     assert re.search(rf"%[\w.]*{CALL_NAMES[kernel]}[\w.]* = [^\n]*"
                      r'custom_call_target="tpu_custom_call"',
                      compiled.as_text())
+
+
+def _plan(g):
+    """Every conv and projection co-executed (3/4-1/4), attention
+    exclusive: fused segments, pool and exclusive singletons."""
+    decisions, opaque = {}, {}
+    for n in g:
+        if n.kind in ("linear", "conv"):
+            c = n.op.C_out
+            decisions[n.id] = PartitionDecision(
+                op=n.op, c_cpu=c // 4, c_gpu=c - c // 4,
+                pred_cpu_us=1.0, pred_gpu_us=1.0, pred_total_us=2.0)
+        elif n.kind == "attention":
+            opaque[n.id] = 1.0
+    prov = PlanProvenance(
+        device="moto2022", threads=3, mechanism="svm_poll", step=8, seed=1,
+        network_fingerprint=g.fingerprint(), predictor_checksum="")
+    return CoexecPlan(
+        provenance=prov, schedule=build_graph_schedule(g, decisions, opaque),
+        graph_json=None if g.is_unit_chain() else g.to_json(),
+        segments=segments_json(g, decisions))
+
+
+#: (graph, dtype, the kernels' call names and how many of each)
+WALKS = {
+    "resnet18": (lambda: from_units(networks.resnet18()), "float32",
+                 {"split_matmul": 1}),
+    # the classifier's 123.6 M parameters left out: the Winograd kernel
+    # serves five of the thirteen convolutions
+    "vgg16_convs": (lambda: from_units([u for u in networks.vgg16()
+                                        if u[0] != "linear"]), "float32",
+                    {"winograd_conv": 5}),
+    "tiny_decoder": (lambda: from_model("tiny_decoder", blocks=2,
+                                        cache_len=512), "bfloat16",
+                     {"split_matmul": 9, "vmap_decode_attention_": 2}),
+}
+
+
+@pytest.mark.parametrize("network", sorted(WALKS))
+def test_fused_walk_compiles_for_v5e_without_a_fence(one_chip, network):
+    graph, dtype, calls = WALKS[network]
+    exe = PlanExecutor(_plan(graph()), dtype=jnp.dtype(dtype),
+                       use_pallas=True)
+    x = exe.input_template()
+    walk = exe.walk_program(tuple(x.shape))
+    assert len(walk.segments) > 1
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    text = walk.fn.lower(on_chip(x),
+                         jax.tree.map(on_chip, walk.weights)).compile(
+                         ).as_text()
+    assert "conditional(" not in text and "opt-barrier" not in text
+    names = re.findall(r"^\s*(?:ROOT )?%([\w.]+?)(?:\.\d+)? = [^\n]*"
+                       r'custom_call_target="tpu_custom_call"', text, re.M)
+    assert {n: names.count(n) for n in set(names)} == calls

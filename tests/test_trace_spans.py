@@ -112,18 +112,17 @@ def test_fused_walk_spans_nest_and_count_the_syncs(tmp_path):
     segs = _named(spans, "repro.exec.segment")
     syncs = _named(spans, "repro.exec.sync")
     assert len(runs) == 2
-    assert len(segs) == len(syncs) == 2 * rep_on.sync_points
-    assert rep_on.sync_points == len(exe.segment_programs())
+    # the whole walk is one program: one segment span and one sync a run
+    assert len(segs) == len(syncs) == 2 * rep_on.sync_points == 2
+    assert rep_on.segments == len(exe.walk_program().segments) > 1
     for r in runs:
         assert sum(_inside(s, r) for s in syncs) == rep_on.sync_points
+        assert sum(_inside(g, r) for g in segs) == 1
     for s in syncs:
         assert any(_inside(s, g) for g in segs)
     for g in segs:
         assert any(_inside(g, r) for r in runs)
-    progs = exe.segment_programs()
-    assert [(g[3]["index"], g[3]["mode"]) for g in segs[:len(progs)]] == [
-        (p.index, "fused" if p.fn is not None else p.modes[p.node_ids[0]])
-        for p in progs]
+    assert [(g[3]["index"], g[3]["mode"]) for g in segs] == [(0, "fused")] * 2
 
 
 def test_node_walk_spans_nest_and_count_the_syncs(tmp_path):
@@ -169,7 +168,7 @@ def test_walk_timings_are_the_span_readings(monkeypatch, fused):
             if s.name == "repro.exec.segment"]
     assert all(w > 0.0 for w in segs)
     if fused:
-        assert rep.segment_wall_us == segs
+        assert len(segs) == 1 and rep.segment_wall_us == segs
         assert sum(t.wall_us for t in rep.timings) == pytest.approx(
             sum(segs), rel=1e-12)
     else:
@@ -181,22 +180,15 @@ def test_walk_timings_are_the_span_readings(monkeypatch, fused):
 def test_segment_programs_carry_their_index_and_node_scopes():
     exe = _executor()
     x = exe.input_template()
-    acts = {None: x}
-    pos = {n.id: i for i, n in enumerate(exe.graph)}
-    fused = 0
-    for sp in exe.segment_programs(tuple(x.shape)):
-        src = acts[sp.ext_inputs[0]]
-        if sp.fn is None:                   # the pool singleton
-            spec = exe.specs[pos[sp.node_ids[0]]]
-            acts[sp.node_ids[-1]] = exe._pool(src, spec.pool_bytes)
-            continue
-        fused += 1
-        text = sp.fn.lower([src], sp.weights).as_text(debug_info=True)
-        assert f"jit_segment_{sp.index}" in text
+    walk = exe.walk_program(tuple(x.shape))
+    text = walk.fn.lower(x, walk.weights).as_text(debug_info=True)
+    assert "jit_walk" in text
+    kinds = []
+    for sp in walk.segments:
+        kinds.append(sp.kind)
         for nid in sp.node_ids:
-            assert f"jit(segment_{sp.index})/{nid}/" in text
-        acts[sp.node_ids[-1]] = sp.fn([src], sp.weights)
-    assert fused == 3
+            assert f"jit(walk)/segment_{sp.index}/{nid}/" in text
+    assert kinds.count("fused") == 3 and kinds.count("pool") == 1
 
 
 def test_span_without_a_profiler_session_only_times():
